@@ -12,9 +12,9 @@ per-run asserts inside ``bench_dist`` — owns the dist contracts:
   single-process serve rows never see;
 * **coverage**: every baseline row must still be emitted by the fresh run
   (a silently dropped shard count would freeze its trend forever);
-* **bit-identity**: every fresh qps row must carry
-  ``bit_identical=True`` in its derived string — the worker verifies
-  sharded scores against a process-local engine, and a row that stops
+* **correctness**: every fresh qps row must carry ``within_tol=True`` in
+  its derived string — the worker verifies sharded scores against the
+  float32 reference within the stated tolerance, and a row that stops
   verifying is a correctness failure, not a perf one;
 * **observability**: every fresh qps row must have a sibling
   ``.../breakdown`` row (per-phase pack/dispatch/device/unpack means from
@@ -67,13 +67,13 @@ def check(baseline: dict, fresh: dict, max_regress: float) -> list[str]:
                 f"({delta:+.0%} > {max_regress:.0%} budget)")
         print(f"{name:44s} {b:10.1f} {f:10.1f} {delta:+7.0%}{mark}")
 
-    # -- bit-identity + breakdown sibling on the FRESH run -------------------
+    # -- correctness + breakdown sibling on the FRESH run --------------------
     fresh_bd = _rows(fresh, breakdown=True)
     for name in sorted(fresh_rows):
-        if "bit_identical=True" not in fresh_rows[name].get("derived", ""):
+        if "within_tol=True" not in fresh_rows[name].get("derived", ""):
             failures.append(
-                f"bit-identity: {name} no longer verifies against the "
-                f"process-local engine "
+                f"correctness: {name} no longer verifies against the "
+                f"float32 reference "
                 f"(derived={fresh_rows[name].get('derived')!r})")
         if f"{name}/breakdown" not in fresh_bd:
             failures.append(f"missing breakdown row: {name}/breakdown")
@@ -104,7 +104,7 @@ def main() -> int:
         for msg in failures:
             print(f"  - {msg}")
         return 1
-    print("\nOK: dist rows within trend budget, identity + breakdown hold")
+    print("\nOK: dist rows within trend budget, tolerance + breakdown hold")
     return 0
 
 

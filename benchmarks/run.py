@@ -16,7 +16,7 @@ import json
 import jax
 import jax.numpy as jnp
 
-from repro.common import timeit
+from repro.common import enable_compile_cache, timeit
 from repro.core.mari import (mari_flops, matmul_mari, matmul_mari_fragmented,
                              matmul_vanilla, vanilla_flops)
 
@@ -405,7 +405,8 @@ def bench_serve(scale: float = 0.12, B: int = 2000, iters: int = 15,
 def bench_dist(shards=(1, 2, 4), pool: int = 2000, users: int = 4,
                passes: int = 5, scale: float = 0.05, modes: str = "mari",
                two_process: bool = True):
-    """Candidate-axis sharded stage 2 at increasing shard counts.
+    """Candidate-axis sharded stage 2 at increasing shard counts — a
+    CPU-only rehearsal of the sharding path, not a device measurement.
 
     Each row runs in a subprocess (``repro.dist.runner``) so every shard
     count gets its own forced host-device world; the final row exercises
@@ -413,16 +414,26 @@ def bench_dist(shards=(1, 2, 4), pool: int = 2000, users: int = 4,
     physical CPU the forced devices share cores, so qps-vs-shards mostly
     reports sharding overhead, not speedup — the row the trajectory
     tracks is that overhead staying flat. Scores per run are verified
-    bit-identical against the process-local engine (--verify).
+    against the float32 reference within the stated tolerance (--verify).
+
+    CPU only: this process has already touched JAX, so on a chip host it
+    holds the chips and its children could not reach them.
     """
     import os
     import subprocess
     import sys
 
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"--bench dist is a CPU-only rehearsal (forced host devices in "
+            f"child processes); this process runs on "
+            f"{jax.default_backend()!r} and holds its devices — run it "
+            f"with JAX_PLATFORMS=cpu")
     src = os.path.join(os.path.dirname(__file__), "..", "src")
 
     def run(n_proc: int, dev_per_proc: int) -> list[dict]:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = (os.path.abspath(src) + os.pathsep
                              + env.get("PYTHONPATH", ""))
         cmd = [sys.executable, "-m", "repro.dist.runner",
@@ -459,7 +470,7 @@ def bench_dist(shards=(1, 2, 4), pool: int = 2000, users: int = 4,
             name = f"dist/{r['mode']}/shards={r['shards']}"
             _row(name, 1e6 / r["qps"],
                  f"procs=1;pool={r['pool']};users={r['users']};"
-                 f"qps={r['qps']};bit_identical={r.get('bit_identical')}")
+                 f"qps={r['qps']};within_tol={r.get('within_tol')}")
             breakdown_row(name, r)
     if two_process:
         nproc_dev = max(max(shards) // 2, 1)
@@ -468,7 +479,7 @@ def bench_dist(shards=(1, 2, 4), pool: int = 2000, users: int = 4,
             name = f"dist/{r['mode']}/shards={r['shards']}/procs=2"
             _row(name, 1e6 / r["qps"],
                  f"procs=2;pool={r['pool']};users={r['users']};"
-                 f"qps={r['qps']};bit_identical={r.get('bit_identical')}")
+                 f"qps={r['qps']};within_tol={r.get('within_tol')}")
             breakdown_row(name, r)
     _JSON_EXTRA["dist"] = {"config": "paper_ranking", "scale": scale,
                            "pool": pool, "users": users, "passes": passes,
@@ -632,6 +643,7 @@ def main() -> None:
                     help="also write machine-readable results (e.g. "
                          "BENCH_serve.json) for perf-trajectory tracking")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.bench in ("table2", "all"):
         bench_table2(args.scale)

@@ -11,8 +11,9 @@ Part 2 — async cross-user coalescing: a simulated multi-user burst (ragged
 pool sizes, mixed cache hits/misses) is submitted concurrently to the
 ``CoalescingBatcher``, which packs candidate chunks from different users
 into shared stage-2 buckets — each executed as ONE row-wise call (every
-candidate row gathers its own user's cached reps). Scores are bit-identical
-to the sequential per-request loop; throughput is reported for both.
+candidate row gathers its own user's cached reps). Scores of both loops are
+checked against the plain float32 reference within the stated tolerance
+(``repro.serve.reference``); throughput is reported for both.
 
 Part 3 — overload & SLO admission: the same graph behind a
 ``RankingService`` with the continuous dispatch loop and deliberately tiny
@@ -41,7 +42,9 @@ from repro.data.features import make_recsys_feeds
 from repro.graph.executor import init_graph_params
 from repro.models.ranking import PaperRankingConfig, build_paper_ranking_model
 from repro.serve import (AdmissionError, CoalescingBatcher, RankingService,
-                         SLO_DEADLINE, ServePlan, ServeRequest, ServingEngine)
+                         SLO_DEADLINE, ReferenceScorer, ServePlan,
+                         ServeRequest, ServingEngine)
+from repro.serve.reference import tol_ratio, tolerance
 
 
 def main():
@@ -93,10 +96,19 @@ def main():
             key, k = jax.random.split(key)
             yield make_request(r, k, args.candidates)
 
+    # every served score is checked against the plain float32 reference
+    # (un-rewritten graph, highest matmul precision) within the stated
+    # tolerance: differently shaped executables are not bit-identical
+    reference, tol = ReferenceScorer(graph, params), tolerance()
+
+    def check(reqs, results, what):
+        for req, res in zip(reqs, results):
+            ratio = tol_ratio(res.scores, reference(req), tol)
+            assert ratio <= 1.0, f"{what}: {ratio:.2f}x the tolerance {tol}"
+
     # ---- part 1: VanI vs UOI vs MaRI, sequential per-request loop ----------
     print(f"requests={args.requests} users={args.users} "
           f"candidates/request={args.candidates} max_batch={args.max_batch}")
-    ref_scores = None
     # ONE declarative plan, evolved per paradigm — the three engines differ
     # only in graph.mode (repro.serve.plan is the config spine)
     base_plan = ServePlan().evolve(batch__max_batch=args.max_batch,
@@ -110,19 +122,13 @@ def main():
         if eng.two_stage:
             print(f"[{mode}] {eng.split.summary()}")
         lats, hits, hedges = [], 0, 0
-        last = None
         for req in request_stream(jax.random.PRNGKey(42)):
             res = eng.score(req)
             lats.append(res.latency_ms)
             hits += res.user_cache_hit
             hedges += res.hedged
-            last = res.scores
+        check([req], [res], mode)     # the last request of the stream
         lats = np.asarray(lats[2:])   # drop warm-up/compile
-        if ref_scores is None:
-            ref_scores = last
-        else:
-            err = np.abs(ref_scores - last).max()
-            assert err < 1e-3, f"{mode} diverged from VanI by {err}"
         extra = (f"  stage1_runs={eng.stage1_calls}"
                  f"  stage2_compiles={eng.stage2_compilations}"
                  if eng.two_stage else "")
@@ -132,7 +138,7 @@ def main():
               f"user_cache_hits={hits}/{args.requests}  "
               f"hedged={hedges}{extra}")
         eng.close()
-    print("all modes score-identical ✓")
+    print(f"all modes within {tol} of the float32 reference ✓")
 
     # ---- part 2: async multi-user stream through the coalescing batcher ----
     print(f"\n-- async coalescing (mari): multi-user burst, ragged pools, "
@@ -168,8 +174,8 @@ def main():
         cross = eng.coalesced_calls - cross0
         batches = batcher.batches - batches0
 
-    for s, c in zip(seq_results, co_results):
-        assert np.array_equal(s.scores, c.scores), "coalescing changed scores"
+    check(burst, seq_results, "per-request")
+    check(burst, co_results, "coalesced")
     rows = sum(r.scores.shape[0] for r in co_results)
     print(f"[sequential] {args.requests / seq_s:7.1f} req/s "
           f"({rows / seq_s:10.0f} candidates/s)")
@@ -177,7 +183,8 @@ def main():
           f"({rows / co_s:10.0f} candidates/s)  "
           f"stage2_calls/burst={calls}  "
           f"cross_user_calls={cross}  batches={batches}")
-    print("coalesced scores bit-identical to per-request ✓")
+    print("per-request and coalesced scores within tolerance of the "
+          "reference ✓")
     eng.close()
 
     # ---- part 3: overload burst against SLO-tiered admission control -------

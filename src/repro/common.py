@@ -1,6 +1,8 @@
 """Small shared utilities: rng threading, pytree helpers, timing, shape math."""
 from __future__ import annotations
 
+import os
+import pathlib
 import time
 from typing import Any, Callable, Iterator
 
@@ -10,6 +12,26 @@ import numpy as np
 
 Array = jax.Array
 PyTree = Any
+
+# src/repro/common.py -> the checkout root
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; every entry point calls
+    this at start-up, before its first compile. Returns the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is where the cache lives (JAX
+    reads it itself) and no other directory is set here. Otherwise the
+    cache goes to ``<checkout>/.jax_cache``: a fixed path, because the
+    path is part of what a later run must match to hit. Every compile is
+    kept, however short: a serving run is many small programs."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 class KeySeq:

@@ -11,15 +11,23 @@ closing all-gather (the step's one collective) hands every host the full
 score vector.
 
 Correctness contract (the subprocess test in ``tests/test_dist.py``):
-sharded fp32 scores are **bit-identical** to a process-local single-device
-``ServingEngine`` — candidate-axis sharding only partitions row-parallel
-work, it reassociates nothing.
+with ``--verify`` every worker checks its sharded scores against the plain
+float32 reference (``repro.serve.reference``) within the platform's stated
+tolerance — sharding changes the executable's shapes, and XLA promises no
+bit-equality across shapes.
 
 Usage (spawner re-execs itself as the workers)::
 
-  python -m repro.dist.runner --spawn 2 --devices-per-process 2 --verify
-  python -m repro.dist.runner --spawn 1 --devices-per-process 4 --bench
-  python -m repro.dist.runner --spawn 2 --plan plan.json --verify
+  JAX_PLATFORMS=cpu python -m repro.dist.runner --spawn 2 \
+      --devices-per-process 2 --verify
+  python -m repro.dist.runner --spawn 1 --bench    # a TPU host: all chips
+  JAX_PLATFORMS=cpu python -m repro.dist.runner --spawn 2 --plan plan.json
+
+Devices: the runner never chooses the platform. When the caller has chosen
+the CPU (``JAX_PLATFORMS=cpu``, as tests and rehearsals do), each worker
+gets ``--devices-per-process`` forced host devices. On an accelerator host
+one process drives every local chip, so ``--spawn`` above 1 is refused
+there (several processes cannot share one host's chips).
 
 Each worker prints one JSON record per mode; the spawner re-emits worker
 0's stdout and fails if any worker fails.
@@ -36,13 +44,14 @@ from __future__ import annotations
 import os
 
 # The forced host-device count must be locked in before any jax import
-# (the spawner sets REPRO_HOST_DEVICES in each worker's environment).
-if os.environ.get("REPRO_HOST_DEVICES"):
+# (the spawner sets REPRO_HOST_DEVICES in each worker's environment). It
+# only stands in for chips when the caller already chose the CPU.
+if (os.environ.get("REPRO_HOST_DEVICES")
+        and os.environ.get("JAX_PLATFORMS") == "cpu"):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count="
         + os.environ["REPRO_HOST_DEVICES"])
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse
 import json
@@ -134,7 +143,11 @@ def run_worker(args) -> int:
     import jax
     import numpy as np
 
+    from repro.common import enable_compile_cache
     from repro.serve.engine import ServingEngine
+    from repro.serve.reference import ReferenceScorer, tol_ratio, tolerance
+
+    enable_compile_cache()
 
     graph, params, reqs = build_problem(args.scale, args.pool, args.users)
     pool_rows = sum(next(iter(r.candidate_feeds.values())).shape[0]
@@ -161,17 +174,12 @@ def run_worker(args) -> int:
                                    clock=lambda: float(hb_step[0]))
     records = []
     tracers = {}
+    # process-local float32 reference, shared by every mode (identical
+    # inputs in every worker -> identical references)
+    ref_scores = ([ReferenceScorer(graph, params)(r) for r in reqs]
+                  if args.verify else None)
     for mode in args.modes.split(","):
         mplan = plan.evolve(graph__mode=mode)
-        ref = ref_scores = None
-        if args.verify:
-            # process-local reference: plain single-device engine
-            # (identical inputs in every worker -> identical references)
-            ref = ServingEngine(graph, params, plan=mplan.evolve(
-                shard__shard_candidates=False,
-                shard__compress_scores=False))
-            ref_scores = [r.scores for r in ref.score_coalesced(reqs)]
-
         eng = ServingEngine(graph, params, plan=mplan)
         res = eng.score_coalesced(reqs)         # compile + verify pass
         rec = {"mode": mode, "processes": topo.num_processes,
@@ -182,18 +190,15 @@ def run_worker(args) -> int:
                "compress_scores": bool(compress),
                "plan": mplan.to_dict()}
         if args.verify:
+            atol, rtol = tolerance()
             if compress:
-                # int8 wire: exact identity is forfeit by construction;
-                # per-element error <= that shard's scale/2
-                tol = max(float(np.abs(s).max()) for s in ref_scores) \
-                    / 127.0 / 2.0 + 1e-6
-                ok = all(np.allclose(a.scores, b, atol=tol)
-                         for a, b in zip(res, ref_scores))
-                rec["within_int8_bound"] = bool(ok)
-            else:
-                ok = all(np.array_equal(a.scores, b)
-                         for a, b in zip(res, ref_scores))
-                rec["bit_identical"] = bool(ok)
+                # int8 wire: per-element error <= that shard's scale/2, on
+                # top of the float tolerance
+                atol += max(float(np.abs(s).max()) for s in ref_scores) \
+                    / 127.0 / 2.0
+            ok = all(tol_ratio(a.scores, b, (atol, rtol)) <= 1.0
+                     for a, b in zip(res, ref_scores))
+            rec["within_int8_bound" if compress else "within_tol"] = ok
             if not ok:
                 print(json.dumps(rec), flush=True)
                 print(f"[runner] VERIFY FAILED mode={mode}", file=sys.stderr)
@@ -229,8 +234,6 @@ def run_worker(args) -> int:
         if eng.tracer is not None:
             tracers[mode] = eng.tracer    # events outlive the engine
         eng.close()
-        if ref is not None:
-            ref.close()
         if topo.process_id == 0:
             print(json.dumps(rec), flush=True)
     if args.trace:
@@ -251,6 +254,13 @@ def spawn(args) -> int:
     """
     import tempfile
 
+    on_cpu = os.environ.get("JAX_PLATFORMS") == "cpu"
+    if args.spawn > 1 and not on_cpu:
+        print("[runner] --spawn > 1 starts several processes on this host, "
+              "which only the CPU can serve (set JAX_PLATFORMS=cpu for "
+              "forced host devices); on an accelerator host one process "
+              "drives every local chip: use --spawn 1", file=sys.stderr)
+        return 2
     port = args.port or _free_port()
     workers = []
     for pid in range(args.spawn):
@@ -259,8 +269,9 @@ def spawn(args) -> int:
             "REPRO_NUM_PROCESSES": str(args.spawn),
             "REPRO_PROCESS_ID": str(pid),
             "REPRO_COORDINATOR": f"localhost:{port}",
-            "REPRO_HOST_DEVICES": str(args.devices_per_process),
         })
+        if on_cpu:
+            env["REPRO_HOST_DEVICES"] = str(args.devices_per_process)
         src = os.path.join(os.path.dirname(__file__), "..", "..")
         env["PYTHONPATH"] = (os.path.abspath(src) + os.pathsep
                              + env.get("PYTHONPATH", ""))
@@ -318,7 +329,9 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--spawn", type=int, default=0,
                     help="spawn N localhost worker processes and exit")
-    ap.add_argument("--devices-per-process", type=int, default=2)
+    ap.add_argument("--devices-per-process", type=int, default=2,
+                    help="forced host devices per worker (CPU only; an "
+                         "accelerator worker uses every local chip)")
     ap.add_argument("--port", type=int, default=0,
                     help="coordinator port (0 = pick a free one)")
     ap.add_argument("--modes", default=",".join(MODES))
@@ -333,7 +346,8 @@ def main() -> int:
                          "value, else 16)")
     ap.add_argument("--passes", type=int, default=5)
     ap.add_argument("--verify", action="store_true",
-                    help="assert sharded == local fp32 scores bit-identically")
+                    help="check sharded scores against the float32 "
+                         "reference within the stated tolerance")
     ap.add_argument("--bench", action="store_true",
                     help="emit qps rows per mode")
     ap.add_argument("--device-resident", action="store_true",

@@ -51,11 +51,7 @@ class Topology:
         if self.is_distributed:
             # CPU backends cross processes via gloo; TPU backends ignore
             # the setting and use ICI/DCN.
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except AttributeError:
-                pass  # older/newer jax without the knob: backend default
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
             jax.distributed.initialize(
                 coordinator_address=self.coordinator,
                 num_processes=self.num_processes,

@@ -1,7 +1,7 @@
 """Self-healing primitives: circuit breaker + retry policy.
 
 ``CircuitBreaker`` guards the stage-2 device-resident fast path.  The
-classic three-state walk, tuned for a path that has a *bit-identical
+classic three-state walk, tuned for a path that has a *same-answer
 fallback* (re-stacking) rather than an error response:
 
 * CLOSED — traffic flows; ``failures`` consecutive recorded failures

@@ -180,7 +180,7 @@ def _mari_dense_operands(node: Node, params: dict, vals: dict):
 
 
 def _run_mari_dense(node: Node, params: dict, vals: dict, *,
-                    use_pallas: bool = False, interpret: bool = True,
+                    use_pallas: bool = False, interpret: bool = False,
                     user_index: Array | None = None) -> Array:
     """Eq. 7: Tile(Σ_user x_u W_u, B) + Σ_rest x W  — tile realized as a
     broadcast add (never materialized).
@@ -225,11 +225,17 @@ class Executor:
             raise ValueError(f"mode must be 'vani' or 'uoi', got {mode!r}")
         self.graph = graph
         self.mode = mode
-        # Backend-gated Pallas dispatch for mari_dense: compiled on TPU,
-        # interpret mode everywhere else (CPU validation).
+        # Backend-gated Pallas dispatch: compiled on TPU, the interpreter on
+        # CPU (validation). Any other backend would silently run the
+        # interpreter in place of the device, so it is refused.
         self.use_pallas = use_pallas
         if pallas_interpret is None:
-            pallas_interpret = jax.default_backend() != "tpu"
+            backend = jax.default_backend()
+            if use_pallas and backend not in ("cpu", "tpu"):
+                raise ValueError(
+                    f"use_pallas on backend {backend!r}: the Pallas kernels "
+                    f"compile for 'tpu' and run interpreted only on 'cpu'")
+            pallas_interpret = backend == "cpu"
         self.pallas_interpret = pallas_interpret
         self.gather_attention = gather_attention
         self._user_inputs = {

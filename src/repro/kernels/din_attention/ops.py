@@ -12,7 +12,7 @@ from repro.kernels.din_attention.kernel import din_attention_kernel
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def din_attention(query, keys, mask, w1, b1, w2, b2, w3, b3, *,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """query (B, D); keys (L, D); mask (L,). Returns (B, D)."""
     B = query.shape[0]
     bm = min(128, max(8, B))

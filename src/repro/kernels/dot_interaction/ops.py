@@ -11,7 +11,7 @@ from repro.kernels.dot_interaction.kernel import dot_interaction_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("keep_self", "interpret"))
-def dot_interaction(x, *, keep_self: bool = False, interpret: bool = True):
+def dot_interaction(x, *, keep_self: bool = False, interpret: bool = False):
     """x (B, F, D) -> (B, F*(F±1)/2) pairwise dots (DLRM interaction)."""
     B = x.shape[0]
     bm = min(128, max(8, B))

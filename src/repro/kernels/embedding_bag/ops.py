@@ -13,7 +13,7 @@ from repro.kernels.embedding_bag.kernel import embedding_bag_kernel
 @functools.partial(jax.jit,
                    static_argnames=("num_segments", "combiner", "interpret"))
 def embedding_bag(table, ids, segment_ids, *, num_segments: int,
-                  combiner: str = "sum", interpret: bool = True):
+                  combiner: str = "sum", interpret: bool = False):
     """Pooled multi-hot lookup: out[s] = pool_{i: seg[i]==s} table[ids[i]]."""
     order = jnp.argsort(segment_ids)
     ids_s = ids[order]

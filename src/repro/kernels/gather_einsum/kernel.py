@@ -5,9 +5,9 @@ table plus a per-row ``user_index``; the materializing path gathers the
 table to ``(B, ...)`` before every contraction, which at coalesced batch
 sizes re-creates exactly the HBM traffic MaRI's one-shot tensors were
 built to avoid (for reparam DIN the gathered ``T`` block is ``(B, L, D, h)``).
-This kernel family folds the gather into the contraction: each row tile
-loads its rows from the VMEM-resident table at contraction time, so the
-gathered ``(B, ...)`` operand never exists in HBM.
+This kernel family folds the gather into the contraction: each grid step
+loads ONE user's table row into VMEM and contracts it against that user's
+rows, so the gathered ``(B, ...)`` operand never exists in HBM.
 
 Supported specs are the decomposed-attention contractions — the first
 operand is per-row (leading ``b``), the second is the stacked table
@@ -17,14 +17,18 @@ operand is per-row (leading ``b``), the second is the stacked table
 * ``"bl,uld->bd"``   — attention weights against the boundary keys;
 * ``"blh,uh->bl"``   — per-row contraction against a per-user vector table.
 
-Grid: 1-D over row tiles of ``bm`` rows. Per step the kernel holds the x
-tile ``(bm, ...)``, the FULL table ``(U, ...)`` and the tile's indices
-``(bm, 1)`` in VMEM; ``U`` is the pow2-padded user-slot count of one
-coalesced batch (small by construction — ``max_users_per_batch``), so the
-table tile is the whole memory footprint and it is shared across row tiles.
-Row results depend only on ``x[b]`` and ``table[idx[b]]`` — not on ``U``,
-``B``, or the tile packing — which is what makes a single request (U=1)
-bit-identical to the coalesced path.
+Layout: the caller (ops.py) sorts rows by user, so each user's rows form
+one run and a row tile of ``bm`` rows meets only the users whose runs
+cross it. The grid is 1-D over (row tile, user) pairs — at most
+``B/bm + min(U, B) - 1`` steps — whose tile and user ids are
+scalar-prefetched to SMEM and drive the ``index_map``s. Per step the kernel
+holds the x tile, ONE user's ``(1, ...)`` table row and the tile's sorted
+indices; consecutive steps with the same tile or the same user reuse the
+resident block, so each user's row crosses HBM about once and VMEM use
+does not grow with U. A step writes only the rows whose index equals its
+user (the output tile stays resident across the steps that share it);
+padding steps beyond the real pair count are flagged off and do nothing.
+Row results depend only on ``x[b]`` and ``table[idx[b]]``.
 """
 from __future__ import annotations
 
@@ -33,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def parse_spec(spec: str) -> tuple[str, str, str, str]:
@@ -64,58 +69,115 @@ def parse_spec(spec: str) -> tuple[str, str, str, str]:
     return x_sub, t_sub, out, f"{x_sub},b{t_sub[1:]}->{out}"
 
 
-def _kernel(x_ref, t_ref, idx_ref, o_ref, *, row_spec):
-    # Gather-at-load: this tile's rows of the stacked table, straight from
-    # the VMEM-resident (U, ...) block — (B, ...) never exists in HBM.
-    idx = idx_ref[...][:, 0]
-    rows = jnp.take(t_ref[...], idx, axis=0)
-    o_ref[...] = jnp.einsum(
-        row_spec, x_ref[...], rows,
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+# Per-spec row bodies: (x tile, ONE user's table row, (bm, 1) row mask,
+# output block ref). Each writes the masked rows of its output block.
+
+def _q_against_t(x_ref, t_ref, mask, o_ref):
+    # "bd,uldh->blh" as L small (bm, D) @ (D, h) matmuls; the output block
+    # is (L, bm, h) so each l writes a whole leading-dim slab (ops.py
+    # transposes back to (B, L, h))
+    x = x_ref[...]
+
+    def step(l, carry):
+        y = jnp.dot(x, t_ref[0, l], preferred_element_type=jnp.float32)
+        o_ref[l] = jnp.where(mask, y, o_ref[l]).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, t_ref.shape[1], step, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("spec", "bm", "interpret"))
-def gather_einsum_kernel(spec, x, table, user_index, *, bm=256,
-                         interpret=False):
-    """``einsum(spec, x, table[user_index])`` with the gather fused into the
-    row-tile load.
+def _weights_against_keys(x_ref, t_ref, mask, o_ref):
+    # "bl,uld->bd": one (bm, L) @ (L, D) matmul
+    y = jnp.dot(x_ref[...], t_ref[0], preferred_element_type=jnp.float32)
+    o_ref[...] = jnp.where(mask, y, o_ref[...]).astype(o_ref.dtype)
 
-    ``x`` is ``(B, ...)``, ``table`` the stacked ``(U, ...)`` rep table,
-    ``user_index`` the ``(B,)`` int32 row->user map (caller guarantees
-    in-range values and ``B % bm == 0`` — ops.py clamps and pads).
+
+def _rows_against_vector(x_ref, t_ref, mask, o_ref):
+    # "blh,uh->bl": the table arrives as (U, 1, h) — a lane-dense row
+    y = jnp.sum(x_ref[...] * t_ref[...], axis=-1)
+    o_ref[...] = jnp.where(mask, y, o_ref[...]).astype(o_ref.dtype)
+
+
+# spec -> (row body, output layout of the kernel: "blh" is written as
+# (L, B, h) and transposed by the caller)
+SPECS = {
+    "bd,uldh->blh": (_q_against_t, "lbh"),
+    "bl,uld->bd": (_weights_against_keys, "bd"),
+    "blh,uh->bl": (_rows_against_vector, "bl"),
+}
+
+
+def _kernel(tile_ref, user_ref, valid_ref, x_ref, t_ref, idx_ref, o_ref, *,
+            body):
+    s = pl.program_id(0)
+
+    @pl.when(valid_ref[s] == 1)
+    def _step():
+        body(x_ref, t_ref, idx_ref[...] == user_ref[s], o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "bm", "interpret"))
+def gather_einsum_kernel(spec, x, table, sorted_index, step_tile, step_user,
+                         step_valid, *, bm, interpret=False):
+    """``einsum(spec, x, table[sorted_index])`` over rows sorted by user.
+
+    ``x`` is ``(B, ...)`` with rows sorted so equal indices are contiguous,
+    ``table`` the stacked ``(U, ...)`` rep table, ``sorted_index`` the
+    ``(B,)`` int32 row->user map in that order, and ``step_*`` the
+    ``(S,)`` int32 (row tile, user, valid) pair schedule. Caller guarantees
+    ``B % bm == 0``, in-range indices and a schedule that covers every
+    (tile, user) pair in tile order, and a spec in ``SPECS`` (ops.py
+    checks and builds all of it). Returns the kernel's output layout
+    (``SPECS``), in sorted row order.
     """
-    x_sub, t_sub, out_sub, row_spec = parse_spec(spec)
+    x_sub, t_sub, _, _ = parse_spec(spec)
     if x.ndim != len(x_sub) or table.ndim != len(t_sub):
         raise ValueError(f"gather_einsum {spec!r}: operand ranks "
                          f"{x.shape}/{table.shape} do not match the spec")
     B = x.shape[0]
-    if user_index.shape != (B,):
-        raise ValueError(f"user_index must be ({B},), got {user_index.shape}")
+    if sorted_index.shape != (B,):
+        raise ValueError(f"user_index must be ({B},), got "
+                         f"{sorted_index.shape}")
     assert B % bm == 0, (B, bm)
     sizes = {c: s for c, s in zip(x_sub, x.shape)}
     for c, s in zip(t_sub, table.shape):
         if sizes.setdefault(c, s) != s:
             raise ValueError(f"gather_einsum {spec!r}: dim {c!r} is "
                              f"{sizes[c]} on x but {s} on the table")
-    out_shape = tuple(sizes[c] for c in out_sub)
-    out_tail = out_shape[1:]
-    idx2d = user_index.astype(jnp.int32).reshape(B, 1)
-
-    x_tail = x.shape[1:]
+    body, layout = SPECS[spec]
+    if table.ndim == 2:            # (U, h) -> (U, 1, h): lane-dense rows
+        table = table.reshape(table.shape[0], 1, table.shape[1])
+    out_shape = tuple(sizes[c] for c in layout)
+    b_pos = layout.index("b")
+    out_block = tuple(bm if c == "b" else sizes[c] for c in layout)
     zeros = lambda n: (0,) * n
-    return pl.pallas_call(
-        functools.partial(_kernel, row_spec=row_spec),
-        grid=(B // bm,),
+    t_tail = table.shape[1:]
+
+    def out_map(s, tile, user, valid):
+        return tuple(tile[s] if i == b_pos else 0 for i in range(len(layout)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(step_tile.shape[0],),
         in_specs=[
-            pl.BlockSpec((bm,) + x_tail,
-                         lambda i: (i,) + zeros(len(x_tail))),   # x tile
-            pl.BlockSpec(table.shape,
-                         lambda i: zeros(table.ndim)),  # whole stacked table
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),             # row indices
+            pl.BlockSpec((bm,) + x.shape[1:],
+                         lambda s, tile, user, valid:
+                         (tile[s],) + zeros(x.ndim - 1)),          # x tile
+            pl.BlockSpec((1,) + t_tail,
+                         lambda s, tile, user, valid:
+                         (user[s],) + zeros(len(t_tail))),         # one user
+            pl.BlockSpec((bm, 1),
+                         lambda s, tile, user, valid: (tile[s], 0)),  # idx
         ],
-        out_specs=pl.BlockSpec((bm,) + out_tail,
-                               lambda i: (i,) + zeros(len(out_tail))),
+        out_specs=pl.BlockSpec(out_block, out_map),
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, body=body),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        # the output tile is revisited by consecutive steps: sequential grid
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x, table, idx2d)
+    )(step_tile, step_user, step_valid, x, table,
+      sorted_index.reshape(B, 1))

@@ -51,18 +51,37 @@ def _kernel(x_ref, w_ref, u_ref, o_ref, acc_ref, *, activation):
         o_ref[...] = _EPILOGUES[activation](acc_ref[...]).astype(o_ref.dtype)
 
 
-def _kernel_gather(x_ref, w_ref, u_ref, idx_ref, o_ref, acc_ref, *,
-                   activation):
+def _kernel_gather(idx_ref, x_ref, w_ref, u_hbm, o_ref, acc_ref, rows_ref,
+                   sem, *, activation):
     """Row-wise variant with the user-rep gather folded into the
-    accumulator-init load: ``u_ref`` is the full (U, bn) column tile of the
-    stacked rep table and ``idx_ref`` this row-tile's (bm, 1) user indices;
-    row r initializes from table row ``idx[r]`` — the gathered (B, d)
-    block never exists in HBM. U is small (the pow2-padded user-slot count
-    of one coalesced batch), so the table tile stays VMEM-resident."""
+    accumulator-init load. ``idx_ref`` is the (B,) user index, prefetched
+    to SMEM; ``u_hbm`` the stacked f32 rep table as (U, 1, d), left in HBM
+    (the unit middle dim puts the user on an untiled axis, so one user's
+    row is a legal DMA slice). At the first k step each row r of the tile
+    DMAs its (1, bn) slice of table row ``idx[r]`` into ``rows_ref``, which
+    then seeds the accumulator: neither the gathered (B, d) block nor the
+    whole table is ever resident, so VMEM use does not grow with U."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    bm, bn = acc_ref.shape
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        idx = idx_ref[...][:, 0]
-        acc_ref[...] = jnp.take(u_ref[...], idx, axis=0).astype(jnp.float32)
+        def row_copy(r):
+            return pltpu.make_async_copy(
+                u_hbm.at[pl.ds(idx_ref[i * bm + r], 1), :, pl.ds(j * bn, bn)],
+                rows_ref.at[pl.ds(r, 1)], sem)
+
+        def start(r, carry):
+            row_copy(r).start()
+            return carry
+
+        def wait(r, carry):
+            row_copy(r).wait()
+            return carry
+
+        jax.lax.fori_loop(0, bm, start, 0)
+        jax.lax.fori_loop(0, bm, wait, 0)
+        acc_ref[...] = rows_ref[:, 0, :]
 
     acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
                             preferred_element_type=jnp.float32)
@@ -128,28 +147,36 @@ def mari_matmul_kernel_gather(x_rest, w_rest, u_table, user_index, *,
     ``mari_matmul_kernel(x, w, u_table[user_index])`` — a gather is an
     exact row copy and commutes with the elementwise epilogue.
 
-    Caller guarantees B % bm == 0, d % bn == 0, Dr % bk == 0 (ops.py pads).
+    Caller guarantees B % bm == 0, d % bn == 0, Dr % bk == 0, in-range
+    indices and an f32 table (ops.py pads, clamps and casts).
     """
     B, Dr = x_rest.shape
     d = w_rest.shape[1]
-    U = u_table.shape[0]
     assert B % bm == 0 and d % bn == 0 and Dr % bk == 0, (B, Dr, d, bm, bn, bk)
     if user_index.shape != (B,):
         raise ValueError(f"user_index must be ({B},), got {user_index.shape}")
+    if u_table.dtype != jnp.float32:
+        raise ValueError(f"u_table must be float32 (it is DMAed into the "
+                         f"f32 accumulator), got {u_table.dtype}")
     if activation not in _EPILOGUES:
         raise ValueError(f"unsupported epilogue activation {activation!r}")
-    idx2d = user_index.astype(jnp.int32).reshape(B, 1)
-    return pl.pallas_call(
-        functools.partial(_kernel_gather, activation=activation),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B // bm, d // bn, Dr // bk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),   # x tile
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),   # w tile
-            pl.BlockSpec((U, bn), lambda i, j, k: (0, j)),    # rep-table tile
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),    # row indices
+            pl.BlockSpec((bm, bk), lambda i, j, k, idx: (i, k)),   # x tile
+            pl.BlockSpec((bk, bn), lambda i, j, k, idx: (k, j)),   # w tile
+            pl.BlockSpec(memory_space=pl.ANY),                     # table
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, idx: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bm, 1, bn), jnp.float32),
+                        pltpu.SemaphoreType.DMA],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel_gather, activation=activation),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, d), x_rest.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x_rest, w_rest, u_table, idx2d)
+    )(user_index.astype(jnp.int32), x_rest, w_rest,
+      u_table.reshape(u_table.shape[0], 1, d))

@@ -36,7 +36,7 @@ def _pick_blocks(B: int, Dr: int, d: int, itemsize: int) -> tuple[int, int, int]
 
 @functools.partial(jax.jit, static_argnames=("activation", "interpret"))
 def mari_matmul_fused_groups(parts, b=None, *, acc0=None, user_index=None,
-                             activation="identity", interpret=True):
+                             activation="identity", interpret=False):
     """act(Σ_g Tile-or-stream(x_g @ w_g) + acc0 + b) for (x, w) pairs.
 
     Each x is (1, D_g) (user side — folded into the broadcast row) or
@@ -48,7 +48,7 @@ def mari_matmul_fused_groups(parts, b=None, *, acc0=None, user_index=None,
     table and the kernel gathers row ``user_index[b]`` at accumulator-init
     load — the gathered (B, d) block never materializes (bit-identical:
     the row adds/epilogue commute with the exact row-copy gather).
-    interpret=True on CPU (validation); False on TPU.
+    ``interpret=True`` runs the Pallas interpreter (CPU validation).
     """
     d = parts[0][1].shape[1]
     user = [(x, w) for x, w in parts if x.shape[0] == 1]
@@ -107,11 +107,11 @@ def mari_matmul_fused_groups(parts, b=None, *, acc0=None, user_index=None,
 
 @functools.partial(jax.jit, static_argnames=("activation", "interpret"))
 def mari_matmul_fused(x_user, x_rest, w_user, w_rest, b=None, *,
-                      activation="identity", interpret=True):
+                      activation="identity", interpret=False):
     """act(Tile(x_user @ w_user, B) + x_rest @ w_rest (+ b)) — Eq. 7.
 
     x_user (1, Du), x_rest (B, Dr), w_user (Du, d), w_rest (Dr, d).
-    interpret=True on CPU (validation); False on real TPU.
+    ``interpret=True`` runs the Pallas interpreter (CPU validation).
     """
     return mari_matmul_fused_groups(
         [(x_user, w_user), (x_rest, w_rest)], b,

@@ -40,16 +40,12 @@ def make_host_mesh(shape: tuple[int, ...] = (1, 1),
 
 
 def mesh_context(mesh):
-    """Context manager activating ``mesh`` for a trace, across jax
-    versions: ``jax.set_mesh`` where it exists (>= 0.5), else the Mesh
-    object itself (its legacy context-manager protocol). ``None`` yields
-    a null context."""
+    """Context manager activating ``mesh`` for a trace (``jax.set_mesh``);
+    ``None`` yields a null context."""
     import contextlib
 
     import jax
 
     if mesh is None:
         return contextlib.nullcontext()
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.set_mesh(mesh)

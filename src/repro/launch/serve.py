@@ -25,14 +25,21 @@ dispatch/collect, and one synthetic track per outstanding group.
 even user ids of the synthetic stream into the cold arena, and reports
 cold hits / async promotions after the stream — so a traced run emits
 the ``warm`` / ``cold_hit`` / ``promote`` instants.
+
+The synthetic stream draws user ids from a Zipf law over ``N_USERS`` ids,
+so popular users repeat (cache hits); a user id always carries the same
+user-side features, and every request brings a fresh candidate pool of
+``--candidates`` rows.
 """
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import numpy as np
 
+from repro.common import enable_compile_cache
 from repro.data.features import make_recsys_feeds
 from repro.graph.executor import init_graph_params
 from repro.serve import RankingService, ServePlan, ServeRequest, ServingEngine
@@ -64,23 +71,56 @@ def build_plan(args) -> ServePlan:
         over["batch__continuous"] = args.continuous
     if args.cold_tier is not None:
         over["mem__cold_tier"] = args.cold_tier
+    if args.device_resident is not None:
+        over["cache__device_resident"] = args.device_resident
     if args.trace:
         over["obs__trace"] = True
     return plan.evolve(**over) if over else plan
 
 
-def _warm_half(warm, graph, split, candidates: int, n_uids: int = 8):
-    """Bulk-warm the EVEN user ids of the launcher's ``r % n_uids`` stream
-    into the cold arena. Odd ids stay unwarmed, so one interleaved stream
-    deterministically exercises every tier: even ids cold-hit (and, after
-    enough touches, promote); odd ids pay stage 1 once and then hot-hit."""
-    key = jax.random.PRNGKey(11)
-    items = []
-    for uid in range(0, n_uids, 2):
-        key, k = jax.random.split(key)
-        uf, _ = split(make_recsys_feeds(graph, candidates, k))
-        items.append((uid, uf))
-    return warm(items)
+# size of the synthetic stream's user-id universe
+N_USERS = 8
+
+
+def zipf_users(n: int, n_users: int = N_USERS, a: float = 1.1,
+               seed: int = 7) -> list[int]:
+    """``n`` user ids drawn from a Zipf(``a``) law over ``n_users`` ids
+    (id 0 the most popular): the head users repeat."""
+    p = 1.0 / np.arange(1, n_users + 1) ** a
+    rng = np.random.default_rng(seed)
+    return rng.choice(n_users, size=n, p=p / p.sum()).tolist()
+
+
+def user_feeds(graph, split, uid: int, candidates: int, seed: int = 11):
+    """User ``uid``'s fixed user-side features: one user id always
+    carries the same feeds (the rep cache's keying contract)."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), uid)
+    return split(make_recsys_feeds(graph, candidates, key))[0]
+
+
+def request_stream(graph, split, uids, candidates: int,
+                   seed: int = 7) -> list[ServeRequest]:
+    """One request per user id in ``uids``: the user's fixed features and
+    a fresh ``candidates``-row pool."""
+    key = jax.random.PRNGKey(seed)
+    users, reqs = {}, []
+    for r, uid in enumerate(uids):
+        if uid not in users:
+            users[uid] = user_feeds(graph, split, uid, candidates)
+        cand = split(make_recsys_feeds(graph, candidates,
+                                       jax.random.fold_in(key, r)))[1]
+        reqs.append(ServeRequest(user_id=uid, user_feeds=users[uid],
+                                 candidate_feeds=cand))
+    return reqs
+
+
+def _warm_half(warm, graph, split, candidates: int, n_uids: int):
+    """Bulk-warm the EVEN user ids of the stream into the cold arena. Odd
+    ids stay unwarmed, so one stream exercises every tier: even ids
+    cold-hit (and, after enough touches, promote); odd ids pay stage 1
+    once and then hot-hit."""
+    return warm([(uid, user_feeds(graph, split, uid, candidates))
+                 for uid in range(0, n_uids, 2)])
 
 
 def _summary(tag: str, lats: list[float]) -> None:
@@ -112,20 +152,14 @@ def serve_single(args, plan: ServePlan) -> None:
                 {k: v for k, v in feeds.items() if k not in user_in})
 
     if engine.cold_tier:
-        warmed = _warm_half(engine.warm, graph, split, args.candidates)
+        warmed = _warm_half(engine.warm, graph, split, args.candidates,
+                            N_USERS)
         print(f"[serve] warmed {warmed} users into the cold tier")
     lats = []
-    key = jax.random.PRNGKey(7)
-    for r in range(args.requests):
-        key, k = jax.random.split(key)
-        feeds = make_recsys_feeds(graph, args.candidates, k)
-        req = ServeRequest(
-            user_id=r % 8,
-            user_feeds={k2: v for k2, v in feeds.items() if k2 in user_in},
-            candidate_feeds={k2: v for k2, v in feeds.items()
-                             if k2 not in user_in})
-        res = engine.score(req)
-        lats.append(res.latency_ms)
+    for req in request_stream(graph, split,
+                              zipf_users(args.requests),
+                              args.candidates):
+        lats.append(engine.score(req).latency_ms)
     if engine.cold_tier:
         engine.flush_promotions()
         ms = engine.mem_stats()
@@ -143,10 +177,16 @@ def serve_single(args, plan: ServePlan) -> None:
              lats[min(2, len(lats) - 1):])   # drop compile warmup
 
 
-def serve_multi(args, plan: ServePlan, scenarios: list[str]) -> None:
+def serve_multi(args, plan: ServePlan, scenarios: list[str],
+                inspect=None) -> None:
     """Route an interleaved request stream across several scenario models
     hosted by one ``RankingService`` (shared rep-cache budget, per-scenario
-    engines + batchers)."""
+    engines + batchers). The whole stream is submitted at once, twice: a
+    compile pass, then the timed pass.
+
+    ``inspect(svc, items, passes, pass_s)``, if given, runs before the
+    service closes, with the ``(scenario, request)`` stream, both passes'
+    results and each pass's wall seconds."""
     with RankingService(plan, smoke=args.smoke) as svc:
         for sc in scenarios:
             svc.register(sc)
@@ -158,23 +198,26 @@ def serve_multi(args, plan: ServePlan, scenarios: list[str]) -> None:
                     lambda items, sc=sc: svc.warm(sc, items),
                     svc.source_graph(sc),
                     lambda feeds, sc=sc: svc.split_feeds(sc, feeds),
-                    args.candidates)
+                    args.candidates, N_USERS)
                 print(f"[serve] scenario={sc} warmed {warmed} users into "
                       f"the cold tier")
-        key = jax.random.PRNGKey(7)
-        items = []
-        for r in range(args.requests):
-            sc = scenarios[r % len(scenarios)]
-            key, k = jax.random.split(key)
-            feeds = make_recsys_feeds(svc.source_graph(sc),
-                                      args.candidates, k)
-            uf, cf = svc.split_feeds(sc, feeds)
-            items.append((sc, ServeRequest(user_id=r % 8, user_feeds=uf,
-                                           candidate_feeds=cf)))
-        svc.score_many(items)                # compile warmup pass, untimed
-        results = svc.score_many(items)
+        uids = zipf_users(args.requests)
+        n = len(scenarios)
+        streams = {sc: iter(request_stream(
+            svc.source_graph(sc),
+            lambda feeds, sc=sc: svc.split_feeds(sc, feeds),
+            uids[k::n], args.candidates)) for k, sc in enumerate(scenarios)}
+        items = [(sc, next(streams[sc]))
+                 for sc in (scenarios[r % n] for r in range(args.requests))]
+        passes, pass_s = [], []
+        for _ in range(2):                   # compile pass, timed pass
+            t0 = time.perf_counter()
+            passes.append(svc.score_many(items))
+            pass_s.append(time.perf_counter() - t0)
+        print(f"[serve] compile pass {pass_s[0]:.2f}s, "
+              f"timed pass {pass_s[1]:.2f}s")
         per = {sc: [] for sc in scenarios}
-        for (sc, _), res in zip(items, results):
+        for (sc, _), res in zip(items, passes[1]):
             per[sc].append(res.latency_ms)
         for sc in scenarios:
             _summary(f"scenario={sc}", per[sc])
@@ -197,12 +240,14 @@ def serve_multi(args, plan: ServePlan, scenarios: list[str]) -> None:
             if tracers:
                 from repro.obs import write_trace
                 write_trace(args.trace, tracers)
-                n = sum(len(t) for t in tracers.values())
+                n_ev = sum(len(t) for t in tracers.values())
                 print(f"[serve] wrote trace -> {args.trace} "
-                      f"({n} events across {len(tracers)} scenarios)")
+                      f"({n_ev} events across {len(tracers)} scenarios)")
+        if inspect is not None:
+            inspect(svc, items, passes, pass_s)
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="din",
                     help="single-scenario architecture (configs registry)")
@@ -249,11 +294,20 @@ def main():
                     help="host-RAM cold rep tier (MemPlan): bulk-warm the "
                          "even user ids of the stream, serve cold hits "
                          "from the arena, promote hot users async")
+    ap.add_argument("--device-resident",
+                    action=argparse.BooleanOptionalAction, default=None,
+                    help="persistent device rep tables (CachePlan."
+                         "device_resident) in place of per-pack re-stacking")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="enable ObsPlan tracing and write a Perfetto-"
                          "loadable Chrome trace-event JSON here")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None, inspect=None) -> None:
+    """Run the launcher on ``argv`` (default: the command line).
+    ``inspect`` is handed to ``serve_multi`` (``--scenario`` runs)."""
+    args = parse_args(argv)
     plan = build_plan(args)
     if args.dump_plan:
         plan.save(args.dump_plan)
@@ -267,10 +321,11 @@ def main():
             s for s in args.scenario.split(",") if s))
         if not scenarios:
             raise SystemExit("--scenario needs at least one scenario name")
-        serve_multi(args, plan, scenarios)
+        serve_multi(args, plan, scenarios, inspect)
     else:
         serve_single(args, plan)
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

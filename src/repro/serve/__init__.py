@@ -6,8 +6,7 @@ grown into an async, multi-user subsystem:
   (user, feature_version) and its outputs are cached; stage 2 (the batched
   residual) is ONE row-wise executable family — each candidate row gathers
   its own user's cached reps via a per-row user index — so a single request
-  (U=1) and a cross-user coalesced batch run the same code and produce
-  bit-identical scores. Options: fused Pallas ``mari_dense`` dispatch
+  (U=1) and a cross-user coalesced batch run the same code. Options: fused Pallas ``mari_dense`` dispatch
   (optionally with the kernel-side user-rep gather), build-time
   grouped-weight pre-concatenation, and candidate-axis sharding on the
   ``repro.dist`` 'cand' mesh — single-process ``jax.sharding`` or SPMD
@@ -41,6 +40,10 @@ grown into an async, multi-user subsystem:
 * ``service`` — ``RankingService``: multi-scenario router hosting several
   registry models behind one ``submit(scenario, request)`` API, with a
   shared rep-cache budget across scenario engines.
+* ``reference`` — ``ReferenceScorer``: the plain float32 reference
+  (un-rewritten graph, ``vani``, highest matmul precision) and the stated
+  per-platform tolerance (``SCORE_TOL``) every cross-shape score check
+  uses — differently shaped executables are not bit-identical.
 * ``errors``  — the serving error taxonomy (``ServeError`` and its typed
   subclasses), stdlib-only so fault specs and recovery policies import
   without the JAX stack.
@@ -105,5 +108,9 @@ from repro.serve.plan import (  # noqa: F401
     PlanResolutionWarning,
     ServePlan,
     ShardPlan,
+)
+from repro.serve.reference import (  # noqa: F401
+    SCORE_TOL,
+    ReferenceScorer,
 )
 from repro.serve.service import RankingService  # noqa: F401

@@ -24,8 +24,9 @@ A single worker thread drains the queue: the first waiting request opens a
 batch, then the worker lingers up to ``linger_ms`` (or until ``max_batch``
 candidate rows / ``max_coalesce`` requests are waiting) collecting
 co-arriving requests before handing the group to the engine. Coalesced
-scores are bit-identical to per-request ``engine.score`` — both run the
-same row-wise executable family.
+and per-request ``engine.score`` run the same row-wise executable family
+at different shapes, so both score within the stated tolerance of the
+float32 reference (``repro.serve.reference``), not bit-identically.
 
 **Continuous dispatch** (``continuous=True``, the default) — instead of
 blocking on each group's results before touching the queue again

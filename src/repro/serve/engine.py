@@ -16,10 +16,15 @@ Execution model — ONE row-wise stage-2 executable for everything:
 * a cross-user coalesced batch stacks the U users' cached stage-1 outputs
   into a rep table and lets each candidate row gather its own user's reps.
 
-Because BOTH paths run the identical executable family, coalesced scores
-are bit-identical to per-request scores (proven by test) — row results of
-the row-parallel residual graph do not depend on batch size, packing
-position, or rep-table size.
+Both paths run the same row-parallel residual graph, so a row's score
+does not depend on which users share its pack — but the executables
+differ in shape (bucket, rep-table size, shard count), and XLA does not
+promise bit-equal results across shapes: tiling and reduction order follow
+the shape, and on a TPU float32 matmuls at default precision run as bf16
+passes. Scores across shapes are therefore checked against the plain
+float32 reference within a stated tolerance (``repro.serve.reference``);
+bit-equality holds only where the same executable sees the same shapes,
+as in lockstep vs continuous dispatch of one request stream.
 
 Configuration is a ``repro.serve.plan.ServePlan`` — the frozen, validated,
 JSON-serializable config spine shared by every entry point::
@@ -462,7 +467,9 @@ class ServingEngine:
         #                                       write while launches were in
         #                                       flight)
         self._inflight: list[_InFlight] = []  # launched, not yet collected
-        self._batch_shapes: set[tuple[int, int]] = set()  # (U_dim, bucket)
+        # (U_dim, bucket) -> abstract (table, user_index, cand) arguments
+        # of the first call at that signature (stage2_executables)
+        self._batch_shapes: dict[tuple[int, int], tuple] = {}
         # first-seen candidate-feed signature {name: (dtype, row shape)} —
         # pack transfer buffers are shaped from it, so a later request
         # drifting from it must fail fast (see _chunk), not be silently
@@ -776,10 +783,19 @@ class ServingEngine:
     def stage2_compilations(self) -> int:
         """Number of compiled batched-stage executables (distinct
         (rep-table, bucket) shape pairs)."""
-        try:
-            return self._stage2._cache_size()
-        except AttributeError:  # older/newer jax: fall back to shape count
-            return len(self._batch_shapes)
+        return self._stage2._cache_size()
+
+    def stage2_executables(self) -> dict[tuple[int, int], jax.stages.Compiled]:
+        """The compiled stage-2 executable of every (rep-table rows,
+        bucket) signature served so far — to inspect what the device runs
+        (``as_text()``: are the Pallas kernels in it, as
+        ``tpu_custom_call``?; ``output_shardings``). Each is lowered again
+        from its first call's shapes; the persistent compilation cache,
+        where on, turns the compile into a read."""
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self._params_s2)
+        return {key: self._stage2.lower(params, *args).compile()
+                for key, args in self._batch_shapes.items()}
 
     @property
     def cache_evictions(self) -> int:
@@ -842,7 +858,7 @@ class ServingEngine:
     # -- scoring ------------------------------------------------------------
     def score(self, req: ServeRequest) -> ServeResult:
         """Score one request — the U=1 degenerate case of the coalesced path
-        (same executable family, hence bit-identical to batched scoring)."""
+        (same executable family, different shape)."""
         return self.score_coalesced([req])[0]
 
     def score_coalesced(self, reqs: Sequence[ServeRequest]
@@ -929,7 +945,7 @@ class ServingEngine:
                 # reps, so they can share a rep-table slot. Without a cache
                 # (incl. single-stage engines) reps are per-request values
                 # with no canonical copy per key — per-request slots keep
-                # coalesced == per-request bit-identity unconditionally, at
+                # each row on its own request's reps unconditionally, at
                 # the cost of repeat users occupying one slot per request.
                 slot_key=((req.user_id, req.feature_version)
                           if self.cache_user_reps else ri)))
@@ -1174,7 +1190,8 @@ class ServingEngine:
         """Map every pack's slot keys to device-table slots (one donated
         row write per user not already resident). ``None`` per pack when
         the device tier is off or that pack overflowed capacity — the pack
-        then falls back to the re-stacking path, bit-identically.
+        then falls back to the re-stacking path (same rows; scores within
+        the stated tolerance, since the table shapes differ).
 
         A user appearing under TWO feature versions in one call also
         forces every pack carrying that user onto the fallback: the
@@ -1182,8 +1199,8 @@ class ServingEngine:
         version would rewrite the slot the first version's rows read —
         within a pack (both keys collapsing to one slot) and across packs
         (a later barrier write clobbering a row an earlier pack
-        references). Re-stacking keeps per-version tables, preserving
-        the bit-identity contract through version bumps.
+        references). Re-stacking keeps per-version tables, so each
+        version's rows read that version's reps.
 
         Every device-resolved user of the CALL is protected while
         resolving: a later pack's write may never steal a slot an
@@ -1197,8 +1214,8 @@ class ServingEngine:
         if self._device_store is None:
             return [None] * len(packs)
         if self.breaker is not None and not self.breaker.allow():
-            # breaker open: route every pack through the bit-identical
-            # re-stacking fallback instead of touching the device tier;
+            # breaker open: route every pack through the re-stacking
+            # fallback (same rows, same scores within tolerance) instead of touching the device tier;
             # after the cooldown, allow() itself flips to half-open and
             # lets probe traffic back onto the fast path
             self.fallback_packs += len(packs)
@@ -1239,8 +1256,8 @@ class ServingEngine:
                 # table generation inconsistent: quarantine it (slots
                 # recycle, tables rebuild lazily from the host LRU) and
                 # route this call's remaining packs through the
-                # re-stacking fallback — the request still succeeds,
-                # bit-identically, while the breaker accumulates the
+                # re-stacking fallback — the request still succeeds, with
+                # the same scores within tolerance, while the breaker accumulates the
                 # failure
                 self._quarantine_device_tier(
                     f"ensure_rows failed: {type(e).__name__}: {e}")
@@ -1342,7 +1359,10 @@ class ServingEngine:
         # first call at a new (rep-table, bucket) signature compiles — that
         # is not a straggler, so hedging would only duplicate the compile
         first_shape = (u_dim, bucket) not in self._batch_shapes
-        self._batch_shapes.add((u_dim, bucket))
+        if first_shape:
+            self._batch_shapes[(u_dim, bucket)] = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                (table, uidx_arr, cand))
         return table, uidx_arr, cand, n_slots, first_shape
 
     # -- dispatch ------------------------------------------------------------

@@ -46,7 +46,7 @@ Sections (each its own frozen dataclass):
   by the remaining deadline budget), ``breaker_failures`` /
   ``breaker_cooldown_ms`` / ``breaker_probes`` (circuit breaker on the
   stage-2 device-resident fast path; open routes packs through the
-  bit-identical re-stacking fallback).
+  re-stacking fallback, which scores the same rows).
 
 Validation happens AT CONSTRUCTION — an invalid combination is either
 rejected (``PlanError``) or auto-resolved with a ``PlanResolutionWarning``

@@ -23,10 +23,11 @@ behind ONE ``submit(scenario, request)`` API:
   compete globally), with cache keys namespaced per scenario so equal user
   ids from different scenarios can never collide on wrong-shaped reps.
 
-Scores are bit-identical to a standalone per-scenario engine: routing adds
-no numerics — the same plan builds the same executable family, and the
-shared cache only changes *when* stage 1 recomputes, never what stage 2
-computes (proven by test).
+Scores match a standalone per-scenario engine within the stated tolerance:
+routing adds no numerics — the same plan builds the same executable
+family, and the shared cache only changes *when* stage 1 recomputes, never
+what stage 2 computes. Only the pack shapes differ, because the batcher
+packs by arrival time (tested).
 
 Usage::
 
@@ -56,6 +57,7 @@ class _Scenario:
     name: str
     plan: ServePlan
     source_graph: Graph          # pre-rewrite graph (feed specs live here)
+    source_params: dict          # its params, before any rewrite
     user_inputs: frozenset[str]  # input names with domain == "user"
     engine: ServingEngine
     batcher: CoalescingBatcher
@@ -96,7 +98,7 @@ class RankingService:
         With no ``graph``, the scenario is built from the ``repro.configs``
         registry by name (``smoke_build``/``BUILD`` per ``smoke``) and
         params are initialized from ``seed`` — deterministic, so a
-        standalone engine built the same way scores bit-identically.
+        standalone engine built the same way scores the same model.
         Returns the scenario's engine.
         """
         if self._closed:
@@ -131,7 +133,8 @@ class RankingService:
         batcher = CoalescingBatcher.from_plan(engine, plan.batch, plan.ft)
         self._scenarios[scenario] = _Scenario(
             name=scenario, plan=plan, source_graph=graph,
-            user_inputs=user_inputs, engine=engine, batcher=batcher)
+            source_params=params, user_inputs=user_inputs, engine=engine,
+            batcher=batcher)
         return engine
 
     # -- lookup -------------------------------------------------------------
@@ -153,6 +156,11 @@ class RankingService:
     def source_graph(self, scenario: str) -> Graph:
         """The scenario's pre-rewrite graph (input/feed specs)."""
         return self._get(scenario).source_graph
+
+    def source_params(self, scenario: str) -> dict:
+        """The scenario's params before any rewrite (what a reference
+        scorer runs ``source_graph`` with)."""
+        return self._get(scenario).source_params
 
     def split_feeds(self, scenario: str, feeds: Mapping[str, jax.Array]
                     ) -> tuple[dict, dict]:

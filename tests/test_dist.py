@@ -1,8 +1,8 @@
 """repro.dist subsystem tests: serving pspecs, the sharding policy, int8
 compression, the collective-aware bucket planner, the kernel-side user-rep
 gather, and multi-PROCESS stage-2 sharding (2 ``jax.distributed`` workers,
-subprocess) — sharded fp32 scores must be bit-identical to the local
-single-device engine across vani/uoi/mari."""
+subprocess) — sharded fp32 scores must match the float32 reference within
+the stated tolerance across vani/uoi/mari."""
 import json
 import os
 import subprocess
@@ -276,26 +276,44 @@ class TestKernelGather:
 # ---------------------------------------------------------------------------
 
 class TestMultiProcessServing:
+    def test_spawn_refuses_several_processes_off_the_cpu(self):
+        """Without JAX_PLATFORMS=cpu the runner picks no platform and
+        forces no host devices: several processes on one host cannot share
+        its chips, so --spawn 2 is refused before any worker starts."""
+        env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+        env["PYTHONPATH"] = (os.path.abspath(_SRC) + os.pathsep
+                             + env.get("PYTHONPATH", ""))
+        p = subprocess.run(
+            [sys.executable, "-m", "repro.dist.runner", "--spawn", "2"],
+            env=env, capture_output=True, text=True, timeout=120)
+        assert p.returncode == 2, p.stderr[-2000:]
+        assert "use --spawn 1" in p.stderr
+
     def test_two_worker_bit_identity(self):
         """2 jax.distributed workers × 2 forced host devices: SPMD sharded
-        stage-2 scores are bit-identical (fp32) to the local single-device
-        engine across vani/uoi/mari, with collective-aware bucketing on."""
+        stage-2 scores match the float32 reference within the stated CPU
+        tolerance across vani/uoi/mari, with collective-aware bucketing
+        on (differently shaped executables are not bit-identical)."""
         env = dict(os.environ)
         env["PYTHONPATH"] = (os.path.abspath(_SRC) + os.pathsep
                              + env.get("PYTHONPATH", ""))
         env.setdefault("JAX_PLATFORMS", "cpu")
+        # the workers are an entry point and turn the persistent compile
+        # cache on; a test run leaves no cache behind
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
         # --max-batch 100 is deliberately non-pow2: the sharded engines
-        # normalize it to a shard-divisible pow2 cap while the local
-        # reference keeps the raw cap — different packing, same rows, so
-        # bit-identity here also proves packing independence
+        # normalize it to a shard-divisible pow2 cap — different packing,
+        # same rows, so the reference check also covers packing
         p = subprocess.run(
             [sys.executable, "-m", "repro.dist.runner", "--spawn", "2",
              "--devices-per-process", "2", "--verify",
              "--max-batch", "100", "--modes", "vani,uoi,mari"],
             env=env, capture_output=True, text=True, timeout=570)
         assert p.returncode == 0, (p.stdout[-2000:], p.stderr[-3000:])
-        recs = [json.loads(line) for line in p.stdout.strip().splitlines()]
-        done = [r for r in recs if r.get("bit_identical")]
+        # gloo's connection notices share the workers' stdout
+        recs = [json.loads(line) for line in p.stdout.strip().splitlines()
+                if line.startswith("{")]
+        done = [r for r in recs if r.get("within_tol")]
         assert {r["mode"] for r in done} == {"vani", "uoi", "mari"}
         assert all(r["processes"] == 2 and r["shards"] == 4 for r in done)
         assert recs[-1] == {"ok": True, "records": 3}

@@ -36,7 +36,7 @@ class TestMariMatmul:
         wu = jax.random.normal(ks[2], (Du, d), dtype)
         wr = jax.random.normal(ks[3], (Dr, d), dtype)
         b = jax.random.normal(ks[4], (d,), dtype)
-        out = mari_matmul_fused(xu, xr, wu, wr, b)
+        out = mari_matmul_fused(xu, xr, wu, wr, b, interpret=True)
         ref = mari_matmul_ref(xu, xr, wu, wr, b)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32),
@@ -47,7 +47,8 @@ class TestMariMatmul:
         out = mari_matmul_fused(jax.random.normal(ks[0], (1, 16)),
                                 jax.random.normal(ks[1], (32, 24)),
                                 jax.random.normal(ks[2], (16, 8)),
-                                jax.random.normal(ks[3], (24, 8)))
+                                jax.random.normal(ks[3], (24, 8)),
+                                interpret=True)
         assert out.shape == (32, 8) and np.isfinite(out).all()
 
     @pytest.mark.parametrize("activation", ["relu", "sigmoid", "gelu", "tanh"])
@@ -61,7 +62,8 @@ class TestMariMatmul:
         wu = jax.random.normal(ks[2], (Du, d))
         wr = jax.random.normal(ks[3], (Dr, d))
         b = jax.random.normal(ks[4], (d,))
-        out = mari_matmul_fused(xu, xr, wu, wr, b, activation=activation)
+        out = mari_matmul_fused(xu, xr, wu, wr, b, activation=activation,
+                                interpret=True)
         ref = mari_matmul_groups_ref([(xu, wu), (xr, wr)], b,
                                      activation=activation)
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
@@ -86,7 +88,8 @@ class TestMariMatmulGroups:
         layout = [("u", 5), ("i", 9), ("u", 13), ("i", 3), ("u", 4)]
         parts = self._parts(jax.random.PRNGKey(1), layout, B, d)
         b = jax.random.normal(jax.random.PRNGKey(2), (d,))
-        out = mari_matmul_fused_groups(parts, b, activation=activation)
+        out = mari_matmul_fused_groups(parts, b, activation=activation,
+                                       interpret=True)
         ref = mari_matmul_groups_ref(parts, b, activation=activation)
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
@@ -99,7 +102,7 @@ class TestMariMatmulGroups:
         tiled = jnp.concatenate(
             [jnp.broadcast_to(x, (B,) + x.shape[1:]) for x, _ in parts], -1)
         w = jnp.concatenate([w for _, w in parts], 0)
-        out = mari_matmul_fused_groups(parts)
+        out = mari_matmul_fused_groups(parts, interpret=True)
         np.testing.assert_allclose(out, matmul_vanilla(tiled, w),
                                    rtol=2e-4, atol=2e-4)
 
@@ -109,13 +112,14 @@ class TestMariMatmulGroups:
         B, d = 16, 8
         parts = self._parts(jax.random.PRNGKey(4), [("i", 7)], B, d)
         acc0 = jax.random.normal(jax.random.PRNGKey(5), (1, d))
-        out = mari_matmul_fused_groups(parts, acc0=acc0, activation="relu")
+        out = mari_matmul_fused_groups(parts, acc0=acc0, activation="relu",
+                                       interpret=True)
         ref = mari_matmul_groups_ref(parts, acc0=acc0, activation="relu")
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
     def test_batch_one_all_user(self):
         parts = self._parts(jax.random.PRNGKey(6), [("u", 5), ("u", 3)], 1, 4)
-        out = mari_matmul_fused_groups(parts)
+        out = mari_matmul_fused_groups(parts, interpret=True)
         ref = mari_matmul_groups_ref(parts)
         assert out.shape == (1, 4)
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
@@ -157,6 +161,34 @@ class TestExecutorPallasPath:
         np.testing.assert_allclose(out_jnp, ref, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(out_pal, ref, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(out_pal, out_jnp, rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                                   ("tpu", False),
+                                                   ("gpu", None)])
+    def test_executor_pallas_mode_follows_backend(self, monkeypatch, backend,
+                                                  interpret):
+        """Interpreted only on the CPU, compiled on the TPU, refused
+        elsewhere: the interpreter never stands in for a device."""
+        from repro.graph.executor import Executor
+        g = self._graph("relu", True)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if interpret is None:
+            with pytest.raises(ValueError, match="backend 'gpu'"):
+                Executor(g, "uoi", use_pallas=True)
+        else:
+            assert Executor(g, "uoi",
+                            use_pallas=True).pallas_interpret is interpret
+
+
+@pytest.mark.parametrize("op", [mari_matmul_fused, mari_matmul_fused_groups,
+                                gather_einsum, din_attention,
+                                dot_interaction, embedding_bag],
+                         ids=lambda op: op.__name__)
+def test_kernel_ops_default_to_compiled(op):
+    """A caller on the chip that forgets the flag gets the kernel, not the
+    interpreter."""
+    import inspect
+    assert inspect.signature(op).parameters["interpret"].default is False
 
 
 class TestGatherEinsum:
@@ -256,7 +288,8 @@ class TestEmbeddingBag:
         table = jax.random.normal(ks[0], (V, D))
         ids = jax.random.randint(ks[1], (nnz,), 0, V)
         segs = jax.random.randint(ks[2], (nnz,), 0, S)
-        out = embedding_bag(table, ids, segs, num_segments=S, combiner=combiner)
+        out = embedding_bag(table, ids, segs, num_segments=S,
+                            combiner=combiner, interpret=True)
         ref = embedding_bag_ref(table, ids, segs, S, combiner)
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
@@ -264,7 +297,7 @@ class TestEmbeddingBag:
         table = jnp.ones((8, 4))
         ids = jnp.array([0, 1], jnp.int32)
         segs = jnp.array([2, 2], jnp.int32)   # segments 0,1,3 empty
-        out = embedding_bag(table, ids, segs, num_segments=4)
+        out = embedding_bag(table, ids, segs, num_segments=4, interpret=True)
         np.testing.assert_array_equal(out[0], 0)
         np.testing.assert_array_equal(out[1], 0)
         np.testing.assert_array_equal(out[3], 0)
@@ -276,7 +309,7 @@ class TestEmbeddingBag:
         ids = jax.random.randint(ks[1], (64,), 0, 50)
         segs = jax.random.permutation(
             ks[2], jnp.repeat(jnp.arange(8), 8))
-        out = embedding_bag(table, ids, segs, num_segments=8)
+        out = embedding_bag(table, ids, segs, num_segments=8, interpret=True)
         ref = embedding_bag_ref(table, ids, segs, 8)
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
@@ -286,7 +319,7 @@ class TestDotInteraction:
     @pytest.mark.parametrize("keep_self", [False, True])
     def test_sweep(self, B, F, D, keep_self):
         x = jax.random.normal(jax.random.PRNGKey(B + F), (B, F, D))
-        out = dot_interaction(x, keep_self=keep_self)
+        out = dot_interaction(x, keep_self=keep_self, interpret=True)
         ref = dot_interaction_ref(x, keep_self=keep_self)
         assert out.shape[1] == (F * (F + 1) // 2 if keep_self
                                 else F * (F - 1) // 2)
@@ -305,7 +338,8 @@ class TestDinAttention:
         w2 = jax.random.normal(ks[4], (h1, h2)) * 0.2
         w3 = jax.random.normal(ks[5], (h2, 1)) * 0.2
         b1, b2, b3 = jnp.zeros(h1), jnp.zeros(h2), jnp.zeros(1)
-        out = din_attention(q, keys, mask, w1, b1, w2, b2, w3, b3)
+        out = din_attention(q, keys, mask, w1, b1, w2, b2, w3, b3,
+                            interpret=True)
         ref = din_attention_ref(q, keys, mask, w1, b1, w2, b2, w3, b3)
         np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
 
@@ -334,5 +368,6 @@ class TestDinAttention:
         out = din_attention(q, keys[0], mask[0],
                             p["layer_0"]["w"], p["layer_0"]["b"],
                             p["layer_1"]["w"], p["layer_1"]["b"],
-                            p["layer_2"]["w"], p["layer_2"]["b"])
+                            p["layer_2"]["w"], p["layer_2"]["b"],
+                            interpret=True)
         np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
