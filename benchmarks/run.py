@@ -540,7 +540,7 @@ def bench_attn(B: int = 2000, users: int = 8, iters: int = 5):
             feeds = make_recsys_feeds(graph, 1, jax.random.PRNGKey(uid + 1))
             reps.append(eng._user_reps(ServeRequest(
                 uid, {k: v for k, v in feeds.items() if k in user_in},
-                {}))[0])
+                {}), eng.new_group_id())[0])
         table = {k: jnp.concatenate([r[k] for r in reps], axis=0)
                  for k in reps[0]}
         # AOT-compile once and reuse the executable for memory stats,

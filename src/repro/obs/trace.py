@@ -1,4 +1,10 @@
-"""Bounded ring-buffer tracer for the serving hot path.
+"""Spans of the serving hot path, and the bounded ring-buffer tracer.
+
+``span`` is the one place the hot path records a span: it always opens a
+``jax.profiler.TraceAnnotation`` named ``serve.<name>`` (so a profile
+shows the host's phases beside the device's operations, on one clock),
+adds the span's duration to a ``StageProfiler`` phase when it has one,
+and records it into a ``Tracer`` when tracing is on.
 
 ``StageProfiler`` answers "where does the mean microsecond go";
 it cannot answer "what did THIS request wait on" or "were those two
@@ -46,10 +52,64 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Any, Iterator
+from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_CAPACITY = 65536
+
+
+class span:
+    """One span of the serving hot path, on every clock that records it::
+
+        with span("pack", phase="pack", tracer=trc, profiler=prof,
+                  group=gid, bucket=b):
+            ...
+
+    * always ``TraceAnnotation("serve.pack", group=gid, bucket=b)``:
+      about a microsecond with no profiler session running, and with one
+      the span sits on the profiler's clock beside the device's ops;
+    * with a ``profiler`` (``repro.serve.profile.StageProfiler``) and a
+      ``phase``, the span's duration is added to that phase;
+    * with a ``tracer``, the span is recorded as a complete event named
+      ``name`` carrying the same args.
+
+    ``set(**args)`` adds args known only inside the span (they reach the
+    annotation and the tracer alike); after the block, ``seconds`` holds
+    the span's duration.
+    """
+
+    __slots__ = ("name", "args", "seconds", "_phase", "_tracer",
+                 "_profiler", "_ann", "_t0")
+
+    def __init__(self, name: str, *, phase: str | None = None,
+                 tracer: "Tracer | None" = None, profiler=None,
+                 **args: Any):
+        self.name = name
+        self.args = args
+        self.seconds = 0.0
+        self._phase = phase
+        self._tracer = tracer
+        self._profiler = profiler if phase is not None else None
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation("serve." + self.name, **self.args)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **args: Any) -> None:
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if self._profiler is not None:
+            self._profiler.add(self._phase, self.seconds)
+        if self._tracer is not None:
+            self._tracer.complete(self.name, self._t0, self.seconds,
+                                  **self.args)
 
 
 class Tracer:
@@ -91,20 +151,8 @@ class Tracer:
     def complete(self, name: str, t0: float, dur_s: float, *,
                  track: str | None = None, **args: Any) -> None:
         """Record a finished span with an explicit start + duration (both
-        in perf_counter seconds) — for phases whose timing the caller
-        already measured."""
+        in perf_counter seconds); ``span`` records through this."""
         self._push("X", name, t0, dur_s, track, args or None)
-
-    @contextmanager
-    def span(self, name: str, *, track: str | None = None,
-             **args: Any) -> Iterator[None]:
-        """Time a block as one complete span."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._push("X", name, t0, time.perf_counter() - t0, track,
-                       args or None)
 
     def begin(self, name: str, *, track: str | None = None,
               **args: Any) -> None:

@@ -29,8 +29,12 @@ grown into an async, multi-user subsystem:
   instead of re-stacking reps every call (``CachePlan.device_resident``).
 * ``profile`` — ``StageProfiler``: per-phase wall-clock taxonomy of the
   hot path (stage1/pack/dispatch/device/unpack, plus the loop-level
-  queue_idle/overlap phases), threaded through the engine and surfaced
-  by ``RankingService.stats()`` and the serve bench's breakdown rows.
+  queue_idle phase), threaded through the engine and surfaced by
+  ``RankingService.stats()`` and the serve bench's breakdown rows. Its
+  phases are fed by ``repro.obs.trace.span``, which also puts every span
+  on the JAX profiler's clock as a ``serve.<name>`` annotation: an
+  operator who captures a profile (``jax.profiler.start_server`` +
+  XProf/Perfetto) sees the ``serve.*`` spans beside the device's ops.
 * ``hedging`` — ``HedgePolicy`` (rolling-p99 decision) + ``HedgedRunner``
   (real duplicate execution of straggling chunks, first result wins).
 * ``plan``    — ``ServePlan``: the frozen, validated, JSON-serializable

@@ -117,6 +117,7 @@ from typing import Sequence
 
 from repro.ft.recovery import RetryPolicy
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import span
 # The error taxonomy lives in repro.serve.errors; AdmissionError and
 # BatcherClosedError were defined here historically and are re-exported
 # for back-compat (`from repro.serve.batcher import AdmissionError`).
@@ -502,8 +503,10 @@ class CoalescingBatcher:
         continuous = (self.continuous
                       and hasattr(self.engine, "begin_coalesced"))
         prof = getattr(self.engine, "profiler", None)
+        gid = None        # the id of the next group, taken while waiting
         while True:
-            t_idle = None
+            if gid is None:
+                gid = self.engine.new_group_id()
             try:
                 if inflight:
                     try:
@@ -514,34 +517,32 @@ class CoalescingBatcher:
                         self._collect_one(inflight)
                         continue
                 else:
-                    t_idle = time.perf_counter()
-                    item = self._q.get(timeout=0.05)
+                    # nothing in flight: the device sits idle for this wait
+                    # (left out of the ring tracer: an idle worker would
+                    # push one event every 50 ms out of its bounded buffer)
+                    with span("wait", phase="queue_idle", profiler=prof,
+                              group=gid):
+                        item = self._q.get(timeout=0.05)
             except queue.Empty:
-                if prof is not None:
-                    prof.add("queue_idle", time.perf_counter() - t_idle)
                 if self._stop.is_set():
                     return
                 continue
-            if t_idle is not None and prof is not None:
-                # partial wait before this arrival: nothing was in flight,
-                # so the device sat idle for it
-                idle = time.perf_counter() - t_idle
-                if idle > 1e-4:
-                    prof.add("queue_idle", idle)
             if item.req is None:      # wake marker (close() or stale)
                 continue
-            group = self._form_group(item, inflight)
+            group = self._form_group(item, inflight, gid)
             try:
-                self._launch_group(group, inflight, continuous, prof)
+                self._launch_group(group, inflight, continuous, gid)
             finally:
                 # launched (or resolved): the crash supervisor no longer
                 # owns these items
                 self._forming = []
+                gid = None
             while len(inflight) >= self.max_inflight:
                 self._collect_one(inflight)
             self._harvest(inflight)
 
-    def _form_group(self, item: _Item, inflight: deque) -> list[_Item]:
+    def _form_group(self, item: _Item, inflight: deque,
+                    gid: int | None) -> list[_Item]:
         with self._lock:
             self._queued -= 1
         # crash-visible formation state: if the loop dies past this line,
@@ -549,39 +550,42 @@ class CoalescingBatcher:
         group = self._forming = [item]
         if self._injector is not None:
             self._injector.poke("worker_loop", req=item.seq)
-        rows = self._candidate_rows(item.req)
-        # draining after close(): no linger — ship everything, fast
-        deadline = (time.perf_counter() if self._stop.is_set()
-                    else self._linger_until(item, time.perf_counter()))
-        while (len(group) < self.max_coalesce
-               and rows < self.engine.max_batch):
-            timeout = deadline - time.perf_counter()
-            if timeout <= 0:
-                break
-            if inflight:
-                # linger in short slices so a previous group whose device
-                # results finish MID-linger is harvested immediately — its
-                # waiters must not sit out this group's window
-                self._harvest(inflight)
-                timeout = min(timeout, 5e-4)
-            try:
-                nxt = self._q.get(timeout=timeout)
-            except queue.Empty:
-                continue
-            if nxt.req is None:
-                continue
-            with self._lock:
-                self._queued -= 1
-            group.append(nxt)
-            rows += self._candidate_rows(nxt.req)
-            # a deadline request joining an open group truncates the
-            # remaining linger to its own (shrunken) window
-            deadline = min(deadline,
-                           self._linger_until(nxt, time.perf_counter()))
+        with span("linger", tracer=self.tracer, group=gid) as sp:
+            rows = self._candidate_rows(item.req)
+            # draining after close(): no linger — ship everything, fast
+            deadline = (time.perf_counter() if self._stop.is_set()
+                        else self._linger_until(item, time.perf_counter()))
+            while (len(group) < self.max_coalesce
+                   and rows < self.engine.max_batch):
+                timeout = deadline - time.perf_counter()
+                if timeout <= 0:
+                    break
+                if inflight:
+                    # linger in short slices so a previous group whose
+                    # device results finish MID-linger is harvested
+                    # immediately — its waiters must not sit out this
+                    # group's window
+                    self._harvest(inflight)
+                    timeout = min(timeout, 5e-4)
+                try:
+                    nxt = self._q.get(timeout=timeout)
+                except queue.Empty:
+                    continue
+                if nxt.req is None:
+                    continue
+                with self._lock:
+                    self._queued -= 1
+                group.append(nxt)
+                rows += self._candidate_rows(nxt.req)
+                # a deadline request joining an open group truncates the
+                # remaining linger to its own (shrunken) window
+                deadline = min(deadline,
+                               self._linger_until(nxt, time.perf_counter()))
+            sp.set(reqs=len(group), rows=rows)
         return group
 
     def _launch_group(self, group: list[_Item], inflight: deque,
-                      continuous: bool, prof) -> None:
+                      continuous: bool, gid: int | None) -> None:
         # claim each future before doing work: a waiter that cancelled while
         # its request sat queued is dropped here, and a claimed (RUNNING)
         # future can no longer be cancelled — so set_result below cannot
@@ -601,21 +605,21 @@ class CoalescingBatcher:
         if not claimed:
             return
         reqs = [it.req for it in claimed]
+        # the engine takes the group id the worker's spans already carry
         if not continuous:
             if trc is not None:
-                trc.instant("group_launch",
+                trc.instant("group_launch", group=gid,
                             reqs=[it.seq for it in claimed])
             try:
-                results = self.engine.score_coalesced(reqs)
+                results = self.engine.score_coalesced(reqs, gid=gid)
             except BaseException as e:      # propagate to every waiter
                 self._fail_or_retry(claimed, e)
                 return
-            self._resolve(claimed, results)
+            self._resolve(claimed, results, gid)
             return
         overlapped = bool(inflight)
-        t0 = time.perf_counter()
         try:
-            handle = self.engine.begin_coalesced(reqs)
+            handle = self.engine.begin_coalesced(reqs, gid=gid)
         except BaseException as e:
             self._fail_or_retry(claimed, e)
             return
@@ -625,10 +629,6 @@ class CoalescingBatcher:
             trc.instant("group_launch", group=getattr(handle, "gid", None),
                         reqs=[it.seq for it in claimed],
                         overlapped=overlapped)
-        if overlapped and prof is not None:
-            # host work done UNDER a still-executing previous group — the
-            # time the continuous loop hides beneath device compute
-            prof.add("overlap", time.perf_counter() - t0)
         inflight.append((claimed, handle))
 
     def _harvest(self, inflight: deque) -> None:
@@ -647,7 +647,7 @@ class CoalescingBatcher:
         except BaseException as e:
             self._fail_or_retry(claimed, e)
             return
-        self._resolve(claimed, results)
+        self._resolve(claimed, results, getattr(handle, "gid", None))
 
     # -- failure resolution and retry ---------------------------------------
     def _fail_or_retry(self, items: list[_Item],
@@ -720,17 +720,20 @@ class CoalescingBatcher:
         err.__cause__ = last
         it.fut.set_exception(err)
 
-    def _resolve(self, claimed: list[_Item], results) -> None:
+    def _resolve(self, claimed: list[_Item], results,
+                 gid: int | None) -> None:
         self.batches += 1
         if len(claimed) > 1:
             self.coalesced_requests += len(claimed)
         now = time.perf_counter()
         trc = self.tracer
-        for it, res in zip(claimed, results):
-            if it.degraded:
-                res.degraded = True
-            if it.submitted_at is not None:
-                self.request_latency.record((now - it.submitted_at) * 1e3)
-            if trc is not None and trc.sampled(it.seq):
-                trc.instant("resolve", req=it.seq)
-            it.fut.set_result(res)
+        with span("resolve_group", tracer=trc, group=gid):
+            for it, res in zip(claimed, results):
+                if it.degraded:
+                    res.degraded = True
+                if it.submitted_at is not None:
+                    self.request_latency.record(
+                        (now - it.submitted_at) * 1e3)
+                if trc is not None and trc.sampled(it.seq):
+                    trc.instant("resolve", req=it.seq)
+                it.fut.set_result(res)

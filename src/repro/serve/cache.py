@@ -313,7 +313,7 @@ class DeviceRepStore:
                       if k in self._shardings else t
                       for k, t in tables.items()}
 
-        def _write(tabs, reps, slot):
+        def serve_rep_write(tabs, reps, slot):
             return {k: tabs[k].at[slot].set(reps[k][0]) for k in tabs}
 
         kwargs = {}
@@ -322,11 +322,11 @@ class DeviceRepStore:
                 k: self._shardings.get(k) for k in tables}
         # donate_argnums=0: the previous table generation is consumed in
         # place — a row write costs one row's bandwidth, not a table copy
-        self._writer = jax.jit(_write, donate_argnums=0, **kwargs)
+        self._writer = jax.jit(serve_rep_write, donate_argnums=0, **kwargs)
         # the same update WITHOUT donation: builds a fresh generation and
         # leaves the previous buffer alive for in-flight executables still
         # reading it (see fork_next_write)
-        self._writer_cow = jax.jit(_write, **kwargs)
+        self._writer_cow = jax.jit(serve_rep_write, **kwargs)
         self._tables = tables
 
     # -- slot resolution ----------------------------------------------------

@@ -81,7 +81,10 @@ stage-2 pipeline:
 * **stage profiler** — ``repro.serve.profile.StageProfiler`` splits every
   call into stage1 / pack / dispatch / device / unpack, surfaced via
   ``RankingService.stats()`` and the ``serve/<mode>/breakdown`` benchmark
-  rows.
+  rows. Every phase is a ``repro.obs.trace.span``, so it is also a
+  ``serve.<name>`` profiler annotation carrying ``group=<group id>``, and
+  the executables are named ``serve_stage1`` / ``serve_stage2`` on the
+  device's timeline.
 
 Ordering contract: every device-table row write of a call completes
 before any stage-2 launch of that call — so the donated table writer can
@@ -111,6 +114,7 @@ user a pack references.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 import warnings
 from typing import Hashable, Mapping, Sequence
@@ -128,7 +132,7 @@ from repro.graph.executor import Executor, USER_INDEX_FEED
 from repro.graph.ir import Graph
 from repro.mem import ColdRepStore, PromotionWorker, RepWarmer
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import DEFAULT_CAPACITY, Tracer
+from repro.obs.trace import DEFAULT_CAPACITY, Tracer, span
 from repro.serve.cache import EVICT, DeviceRepStore, UserRepCache
 from repro.serve.errors import FaultInjected
 from repro.serve.hedging import HedgedRunner, HedgePolicy
@@ -366,7 +370,12 @@ class ServingEngine:
                 raise ValueError(
                     f"stage-2 user inputs {sorted(missing)} are not in the "
                     f"split's boundary_specs — stage 1 cannot supply them")
-            self._stage1 = jax.jit(Executor(self.split.stage1, "uoi").run)
+            s1 = Executor(self.split.stage1, "uoi")
+
+            def serve_stage1(params, feeds):
+                return s1.run(params, feeds)
+
+            self._stage1 = jax.jit(serve_stage1)
             self._stage1_inputs = {
                 n.name for n in self.split.stage1.input_nodes()}
             batched_graph = self.split.stage2
@@ -466,6 +475,9 @@ class ServingEngine:
         #                                       (begin_coalesced needed a row
         #                                       write while launches were in
         #                                       flight)
+        self.h2d_bytes = 0                    # candidate + user-index buffer
+        #                                       bytes handed to the device,
+        #                                       padding rows included
         self._inflight: list[_InFlight] = []  # launched, not yet collected
         # (U_dim, bucket) -> abstract (table, user_index, cand) arguments
         # of the first call at that signature (stage2_executables)
@@ -507,7 +519,7 @@ class ServingEngine:
             self._group_wall_hist = self.metrics.histogram("group_wall_ms")
         else:
             self._group_wall_hist = None
-        self._group_seq = 0           # begin_coalesced calls (group ids)
+        self._group_ids = itertools.count(1)  # group ids (new_group_id)
         self._group_slots: set[int] = set()  # outstanding trace tracks
         self._trace_req_seq = 0       # engine-side request sampling seq
         self.hedge_policy = hedge_policy or HedgePolicy()
@@ -708,7 +720,7 @@ class ServingEngine:
                       gather_attention=self.gather_attention)
         lazy = self.lazy_gather_inputs = ex.lazy_gather_inputs
 
-        def fn(params, table, user_index, cand):
+        def serve_stage2(params, table, user_index, cand):
             # clip: padded rows carry a synthesized index (see _run_pack);
             # clamping guarantees even a garbage value reads a real slot
             # instead of wrapping (numpy) or NaN-filling (jax default)
@@ -732,7 +744,7 @@ class ServingEngine:
             # (zero fresh allocations in steady state). params and the
             # persistent rep tables are never donated — they outlive calls.
             kwargs["donate_argnums"] = (2, 3)
-        return jax.jit(fn, **kwargs)
+        return jax.jit(serve_stage2, **kwargs)
 
     # -- candidate mini-batching --------------------------------------------
     def _bucket(self, n: int) -> int:
@@ -813,7 +825,7 @@ class ServingEngine:
         return (user_id if self._cache_scope is None
                 else (self._cache_scope, user_id))
 
-    def _user_reps(self, req: ServeRequest
+    def _user_reps(self, req: ServeRequest, gid: int
                    ) -> tuple[Mapping[str, jax.Array], bool, float, bool]:
         key = (self._scoped_uid(req.user_id), req.feature_version)
         if self.cache_user_reps:
@@ -835,17 +847,16 @@ class ServingEngine:
                 self.cold_misses += 1
         if self.two_stage:
             self._poke("stage1", user=req.user_id)
-            t0 = time.perf_counter()
-            feeds = {k: v for k, v in req.user_feeds.items()
-                     if k in self._stage1_inputs}
-            reps = self._stage1(self.params, feeds)
-            jax.block_until_ready(reps)
-            self.stage1_calls += 1
-            ms = (time.perf_counter() - t0) * 1e3
-            self.profiler.add("stage1", ms / 1e3)
-            if self.tracer is not None:
-                self.tracer.complete("stage1", t0, ms / 1e3,
-                                     user=req.user_id)
+            with span("stage1", phase="stage1", tracer=self.tracer,
+                      profiler=self.profiler, group=gid,
+                      user=req.user_id) as sp:
+                feeds = {k: v for k, v in req.user_feeds.items()
+                         if k in self._stage1_inputs}
+                reps = self._stage1(self.params, feeds)
+                with span("stage1_wait", tracer=self.tracer, group=gid):
+                    jax.block_until_ready(reps)
+                self.stage1_calls += 1
+            ms = sp.seconds * 1e3
         else:
             # single-stage: the "representation" is the raw user feed dict
             # (never cached — cache_user_reps is forced off above: there is
@@ -861,8 +872,8 @@ class ServingEngine:
         (same executable family, different shape)."""
         return self.score_coalesced([req])[0]
 
-    def score_coalesced(self, reqs: Sequence[ServeRequest]
-                        ) -> list[ServeResult]:
+    def score_coalesced(self, reqs: Sequence[ServeRequest], *,
+                        gid: int | None = None) -> list[ServeResult]:
         """Score several users' requests, coalescing candidate chunks that
         share a power-of-two bucket into single cross-user stage-2 calls.
 
@@ -877,9 +888,17 @@ class ServingEngine:
         This is exactly ``collect(begin_coalesced(reqs))`` — the lockstep
         degenerate case of the two-phase API, so lockstep and continuous
         dispatch share one implementation and stay bit-identical."""
-        return self.collect(self.begin_coalesced(reqs))
+        return self.collect(self.begin_coalesced(reqs, gid=gid))
 
-    def begin_coalesced(self, reqs: Sequence[ServeRequest]) -> _InFlight:
+    def new_group_id(self) -> int:
+        """A fresh group id (the ``group`` arg of every span and trace
+        event of one ``begin_coalesced`` call). A caller that records
+        spans before the group exists (the batcher's wait and linger)
+        takes one here and hands it to ``begin_coalesced``."""
+        return next(self._group_ids)
+
+    def begin_coalesced(self, reqs: Sequence[ServeRequest], *,
+                        gid: int | None = None) -> _InFlight:
         """Phase 1 of the two-phase dispatch: stage 1 + packing + the
         table-write barrier, then launch every pack WITHOUT blocking.
 
@@ -891,11 +910,12 @@ class ServingEngine:
         fork (``pipeline_forks``) — the write builds a NEW generation
         instead of donating the old buffer, which in-flight executables
         are still reading, so cold users cost one table copy instead of
-        a pipeline drain."""
+        a pipeline drain. ``gid``: an id from ``new_group_id``, or None
+        for a fresh one."""
         t0 = time.perf_counter()
         trc = self.tracer
-        self._group_seq += 1
-        gid = self._group_seq
+        if gid is None:
+            gid = self.new_group_id()
         g_slot, g_track = -1, None
         if trc is not None:
             # one synthetic trace track per OUTSTANDING group: the lowest
@@ -910,7 +930,12 @@ class ServingEngine:
             g_track = f"group:{g_slot}"
             trc.begin("group", track=g_track, group=gid, reqs=len(reqs))
         try:
-            return self._begin_coalesced_body(reqs, t0, gid, g_track, g_slot)
+            with span("begin_coalesced", tracer=trc, group=gid,
+                      reqs=len(reqs)) as sp:
+                handle = self._begin_coalesced_body(reqs, t0, gid, g_track,
+                                                    g_slot)
+                sp.set(packs=len(handle.packs))
+            return handle
         except BaseException:
             # close the group span on ANY failure after it opened — stage 1,
             # packing, or launch — so traces stay B/E-balanced and the
@@ -927,7 +952,7 @@ class ServingEngine:
         trc = self.tracer
         infos: list[_ReqInfo] = []
         for ri, req in enumerate(reqs):
-            reps, hit, s1ms, chit = self._user_reps(req)
+            reps, hit, s1ms, chit = self._user_reps(req, gid)
             if trc is not None:
                 self._trace_req_seq += 1
                 if trc.sampled(self._trace_req_seq):
@@ -1007,7 +1032,8 @@ class ServingEngine:
         # before any launch — in-place donated writes must never run under
         # an in-flight executable (the fork above covers the case where
         # launches ARE outstanding)
-        with prof.phase("pack"):
+        with span("slots", phase="pack", tracer=trc, profiler=prof,
+                  group=gid):
             dslots = self._resolve_device_slots(packs, cold_keys)
         if forked:
             # the anticipated write may never have happened (e.g. every
@@ -1023,23 +1049,16 @@ class ServingEngine:
         launched = []
         try:
             for (pack_items, slot_reps, _), ds in zip(packs, dslots):
-                t_pk = time.perf_counter()
-                with prof.phase("pack"):
-                    prep = self._prepare_pack(pack_items, slot_reps, ds)
-                t_ds = time.perf_counter()
-                launched.append(self._launch_pack(prep,
-                                                  on_slots=ds is not None))
-                if trc is not None:
-                    total = sum(n for _, _, _, n in pack_items)
-                    bucket = int(prep[1].shape[0])     # uidx rows
-                    trc.complete(
-                        "pack", t_pk, t_ds - t_pk, group=gid,
-                        bucket=bucket, rows=total, pad=bucket - total,
-                        users=len(slot_reps),
-                        path="slots" if ds is not None else "restack")
-                    trc.complete("dispatch", t_ds,
-                                 time.perf_counter() - t_ds, group=gid,
-                                 bucket=bucket)
+                total = sum(n for _, _, _, n in pack_items)
+                bucket = self._bucket(total)
+                with span("pack", phase="pack", tracer=trc, profiler=prof,
+                          group=gid, bucket=bucket, rows=total,
+                          pad=bucket - total, users=len(slot_reps),
+                          path="slots" if ds is not None else "restack"):
+                    prep = self._prepare_pack(pack_items, slot_reps, ds,
+                                              bucket, gid)
+                launched.append(self._launch_pack(
+                    prep, on_slots=ds is not None, gid=gid))
         except BaseException:
             # never leave untracked launches behind: a later call's table
             # write could otherwise run under them
@@ -1053,9 +1072,6 @@ class ServingEngine:
                            track=g_track, slot=g_slot,
                            slots_mask=[ds is not None for ds in dslots])
         self._inflight.append(handle)
-        if trc is not None:
-            trc.complete("begin_coalesced", t0, time.perf_counter() - t0,
-                         group=gid, reqs=len(reqs), packs=len(packs))
         return handle
 
     def _drain_inflight(self) -> None:
@@ -1090,7 +1106,6 @@ class ServingEngine:
         results. Handles may be collected in any order; each exactly
         once."""
         trc = self.tracer
-        t0c = time.perf_counter()
         try:
             self._inflight.remove(handle)
         except ValueError:
@@ -1098,7 +1113,9 @@ class ServingEngine:
                 "collect() on a handle that is not in flight (already "
                 "collected, or from another engine)") from None
         try:
-            return self._collect_body(handle, t0c)
+            with span("collect", tracer=trc, group=handle.gid,
+                      packs=len(handle.packs)):
+                return self._collect_body(handle)
         except BaseException:
             # a mid-sweep failure (injected fault, detected corruption)
             # must not leave untracked launches behind, and the group
@@ -1112,8 +1129,7 @@ class ServingEngine:
                 self._group_slots.discard(handle.slot)
             raise
 
-    def _collect_body(self, handle: _InFlight, t0c: float
-                      ) -> list[ServeResult]:
+    def _collect_body(self, handle: _InFlight) -> list[ServeResult]:
         prof = self.profiler
         trc = self.tracer
         reqs, infos, packs, launched = (handle.reqs, handle.infos,
@@ -1129,10 +1145,12 @@ class ServingEngine:
                 packs, launched, slots_mask):
             total = sum(n for _, _, _, n in pack_items)
             if not blocked:
-                with prof.phase("device"):
+                with span("device", phase="device", tracer=trc,
+                          profiler=prof, group=handle.gid):
                     jax.block_until_ready(out)
             act = self._poke("collect", group=handle.gid)
-            with prof.phase("unpack"):
+            with span("unpack", phase="unpack", tracer=trc, profiler=prof,
+                      group=handle.gid):
                 scores = np.concatenate(
                     [np.asarray(out[o]) for o in self.outputs],
                     axis=-1)[:total]
@@ -1169,12 +1187,9 @@ class ServingEngine:
         wall_ms = (time.perf_counter() - handle.t0) * 1e3
         if self._group_wall_hist is not None:
             self._group_wall_hist.record(wall_ms)
-        if trc is not None:
-            trc.complete("collect", t0c, time.perf_counter() - t0c,
-                         group=handle.gid, packs=len(packs))
-            if handle.track is not None:
-                trc.end("group", track=handle.track, group=handle.gid)
-                self._group_slots.discard(handle.slot)
+        if trc is not None and handle.track is not None:
+            trc.end("group", track=handle.track, group=handle.gid)
+            self._group_slots.discard(handle.slot)
         return [ServeResult(
             scores=np.concatenate(per_req_scores[ri], axis=0),
             latency_ms=wall_ms, n_batches=per_req_packs[ri],
@@ -1268,8 +1283,9 @@ class ServingEngine:
         return out
 
     def _prepare_pack(self, pack_items: list, slot_reps: list,
-                      dslots: list[int] | None):
-        """Assemble one stage-2 call's arguments.
+                      dslots: list[int] | None, bucket: int, gid: int):
+        """Assemble one stage-2 call's arguments at ``bucket`` rows (spans
+        ``fill``, the buffer fill, and ``h2d``, the host->device copies).
 
         ``pack_items`` is a list of (req idx, slot idx, cand chunk,
         n_valid); ``slot_reps`` maps slot idx -> that user's rep dict;
@@ -1282,8 +1298,6 @@ class ServingEngine:
         refilled by a later pack races the pending copy (see the transfer
         comment below)."""
         self._poke("pack")
-        total = sum(n for _, _, _, n in pack_items)
-        bucket = self._bucket(total)
         n_slots = len(slot_reps)
 
         if dslots is not None:
@@ -1304,26 +1318,28 @@ class ServingEngine:
                          for k in slot_reps[0]}
             slot_ids = list(range(n_slots))
 
-        sample_chunk = pack_items[0][2]
-        uidx_buf = np.empty((bucket,), np.int32)
-        cand_bufs = {k: np.empty((bucket,) + tuple(v.shape[1:]), v.dtype)
-                     for k, v in sample_chunk.items()}
-        offset = 0
-        for _, slot, chunk, n in pack_items:
-            uidx_buf[offset:offset + n] = slot_ids[slot]
-            for k, buf in cand_bufs.items():
-                buf[offset:offset + n] = chunk[k]
-            offset += n
-        if offset < bucket:
-            # padding rows duplicate the LAST real row exactly — user slot
-            # and candidate row — in one masked tail write per buffer, so
-            # pad scores are copies of a real score (a cross-user slot-0 /
-            # tail-candidate combination could exceed max|real score| and
-            # inflate the compress_scores int8 quantization scale past the
-            # verified error bound)
-            uidx_buf[offset:] = uidx_buf[offset - 1]
-            for buf in cand_bufs.values():
-                buf[offset:] = buf[offset - 1]
+        with span("fill", tracer=self.tracer, group=gid):
+            sample_chunk = pack_items[0][2]
+            uidx_buf = np.empty((bucket,), np.int32)
+            cand_bufs = {k: np.empty((bucket,) + tuple(v.shape[1:]),
+                                     v.dtype)
+                         for k, v in sample_chunk.items()}
+            offset = 0
+            for _, slot, chunk, n in pack_items:
+                uidx_buf[offset:offset + n] = slot_ids[slot]
+                for k, buf in cand_bufs.items():
+                    buf[offset:offset + n] = chunk[k]
+                offset += n
+            if offset < bucket:
+                # padding rows duplicate the LAST real row exactly — user
+                # slot and candidate row — in one masked tail write per
+                # buffer, so pad scores are copies of a real score (a
+                # cross-user slot-0 / tail-candidate combination could
+                # exceed max|real score| and inflate the compress_scores
+                # int8 quantization scale past the verified error bound)
+                uidx_buf[offset:] = uidx_buf[offset - 1]
+                for buf in cand_bufs.values():
+                    buf[offset:] = buf[offset - 1]
 
         # the buffers above are PRIVATE to this pack — nothing may mutate
         # them after this point. jnp.array's owning host->device copy is
@@ -1343,18 +1359,22 @@ class ServingEngine:
             for buf in cand_bufs.values():
                 if np.issubdtype(buf.dtype, np.floating):
                     buf.fill(np.nan)
-        if self._multiproc:
-            # SPMD: every process holds the identical host values; lift
-            # them onto the cross-process mesh (replicated tables, sharded
-            # candidate rows + index)
-            repl, _, shard, _ = self._in_shardings
-            table = {k: self._globalize(v, repl) for k, v in table.items()}
-            cand = {k: self._globalize(v, shard)
-                    for k, v in cand_bufs.items()}
-            uidx_arr = self._globalize(uidx_buf, shard)
-        else:
-            cand = {k: jnp.array(v) for k, v in cand_bufs.items()}
-            uidx_arr = jnp.array(uidx_buf)
+        self.h2d_bytes += uidx_buf.nbytes + sum(
+            b.nbytes for b in cand_bufs.values())
+        with span("h2d", tracer=self.tracer, group=gid):
+            if self._multiproc:
+                # SPMD: every process holds the identical host values;
+                # lift them onto the cross-process mesh (replicated
+                # tables, sharded candidate rows + index)
+                repl, _, shard, _ = self._in_shardings
+                table = {k: self._globalize(v, repl)
+                         for k, v in table.items()}
+                cand = {k: self._globalize(v, shard)
+                        for k, v in cand_bufs.items()}
+                uidx_arr = self._globalize(uidx_buf, shard)
+            else:
+                cand = {k: jnp.array(v) for k, v in cand_bufs.items()}
+                uidx_arr = jnp.array(uidx_buf)
 
         # first call at a new (rep-table, bucket) signature compiles — that
         # is not a straggler, so hedging would only duplicate the compile
@@ -1366,7 +1386,7 @@ class ServingEngine:
         return table, uidx_arr, cand, n_slots, first_shape
 
     # -- dispatch ------------------------------------------------------------
-    def _launch_pack(self, prep, on_slots: bool = False
+    def _launch_pack(self, prep, *, on_slots: bool, gid: int
                      ) -> tuple[dict, int, bool]:
         """Launch one prepared pack. Returns (outputs, hedged count,
         blocked) — ``blocked`` marks results already materialized (the
@@ -1379,28 +1399,35 @@ class ServingEngine:
         if n_slots > 1:
             self.coalesced_calls += 1
         prof = self.profiler
+        trc = self.tracer
         try:
             self._poke("stage2_dispatch")
         except Exception:
             if on_slots and self.breaker is not None:
                 self.breaker.record_failure()
             raise
-        if self._hedged is not None and not first_shape:
-            with prof.phase("dispatch"):
+        hedge = self._hedged is not None and not first_shape
+        with span("dispatch", phase="dispatch", tracer=trc, profiler=prof,
+                  group=gid, bucket=int(uidx_arr.shape[0]),
+                  first_shape=first_shape):
+            if hedge:
                 out, outcome = self._hedged.run(
-                    self._params_s2, table, uidx_arr, cand)
+                    self._params_s2, table, uidx_arr, cand, gid)
+            else:
+                try:
+                    out = self._execute(self._params_s2, table, uidx_arr,
+                                        cand)
+                except Exception:
+                    if on_slots and self.breaker is not None:
+                        self.breaker.record_failure()
+                    raise
+        if hedge:
             return out, int(outcome.hedged), True
-        with prof.phase("dispatch"):
-            try:
-                out = self._execute(self._params_s2, table, uidx_arr, cand)
-            except Exception:
-                if on_slots and self.breaker is not None:
-                    self.breaker.record_failure()
-                raise
         if self._hedged is not None:
             # compile call of a hedging engine: block here (latency would
             # poison the policy window, so it is not observed either)
-            with prof.phase("device"):
+            with span("device", phase="device", tracer=trc, profiler=prof,
+                      group=gid):
                 jax.block_until_ready(out)
             return out, 0, True
         return out, 0, False
@@ -1415,9 +1442,12 @@ class ServingEngine:
             out = {k: self._cgather(v) for k, v in out.items()}
         return out
 
-    def _dispatch(self, params, table, uidx, cand):
-        out = self._execute(params, table, uidx, cand)
-        jax.block_until_ready(out)
+    def _dispatch(self, params, table, uidx, cand, gid):
+        """One hedged stage-2 execution, run to completion (on the hedge
+        pool's thread, or inline when the pool is exhausted)."""
+        with span("stage2", tracer=self.tracer, group=gid):
+            out = self._execute(params, table, uidx, cand)
+            jax.block_until_ready(out)
         return out
 
     def invalidate_user(self, user_id: int) -> None:

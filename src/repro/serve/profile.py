@@ -14,17 +14,15 @@ two-stage ranker:
 * ``device``   — waiting on stage-2 results (``block_until_ready``);
 * ``unpack``   — materializing scores to host and slicing per-request
   views out of the bucket;
-* ``queue_idle`` — continuous-loop time with the device idle AND the
-  request queue empty (nothing to overlap — true starvation, not loop
-  overhead);
-* ``overlap``  — host time spent forming-and-launching group k+1 while
-  group k was still executing on device (the work the continuous loop
-  hides under device compute; lockstep dispatch reports zero here).
+* ``queue_idle`` — batcher-worker time blocked on an empty request queue
+  with nothing in flight (true starvation, not loop overhead).
 
 Phases are cumulative wall-clock totals plus call counts, cheap enough to
-stay on permanently (~two ``perf_counter`` calls per phase). The engine
-threads one profiler through its lifetime; ``RankingService.stats()`` and
-``benchmarks/run.py``'s ``serve/<mode>/breakdown`` rows read snapshots.
+stay on permanently. They are fed by ``repro.obs.trace.span``, which puts
+the same spans on the profiler's clock as ``serve.*`` annotations. The
+engine threads one profiler through its lifetime;
+``RankingService.stats()`` and ``benchmarks/run.py``'s
+``serve/<mode>/breakdown`` rows read snapshots.
 
 Thread safety: totals are mutated under a lock because the coalescing
 batcher's worker thread and direct ``score`` callers may profile
@@ -33,12 +31,8 @@ concurrently against one engine.
 from __future__ import annotations
 
 import threading
-import time
-from contextlib import contextmanager
-from typing import Iterator
 
-PHASES = ("stage1", "pack", "dispatch", "device", "unpack",
-          "queue_idle", "overlap")
+PHASES = ("stage1", "pack", "dispatch", "device", "unpack", "queue_idle")
 
 
 class StageProfiler:
@@ -48,15 +42,6 @@ class StageProfiler:
         self._lock = threading.Lock()
         self._total_s: dict[str, float] = {p: 0.0 for p in PHASES}
         self._calls: dict[str, int] = {p: 0 for p in PHASES}
-
-    @contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        """Time one phase occurrence (``with prof.phase("pack"): ...``)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
 
     def add(self, name: str, seconds: float) -> None:
         if name not in self._total_s:

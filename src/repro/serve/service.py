@@ -237,6 +237,9 @@ class RankingService:
                                is not None else None),
                     "stage1_calls": s.engine.stage1_calls,
                     "stage2_calls": s.engine.stage2_calls,
+                    # candidate + user-index bytes handed to the device,
+                    # padding rows included
+                    "h2d_bytes": s.engine.h2d_bytes,
                     "pipeline_forks": s.engine.pipeline_forks,
                     # log-bucketed distributions (repro.obs): the tail
                     # numbers an SLO is judged on, which the cumulative

@@ -255,7 +255,10 @@ class _FlakyEngine:
         self.exc = exc or FaultInjected("boom", site="stage2_dispatch")
         self.calls: list[int] = []
 
-    def score_coalesced(self, reqs):
+    def new_group_id(self):
+        return None
+
+    def score_coalesced(self, reqs, gid=None):
         self.calls.append(len(reqs))
         if len(self.calls) <= self.fail_calls:
             raise self.exc

@@ -2,7 +2,9 @@
 log-bucketed histograms, Chrome trace export, ObsPlan on the plan spine,
 and the tracing/metrics wiring through engine + batcher + service.
 """
+import glob
 import json
+import os
 import threading
 import time
 import warnings
@@ -15,7 +17,7 @@ from repro.data.features import make_recsys_feeds
 from repro.graph.executor import init_graph_params
 from repro.models.ranking import PaperRankingConfig, build_paper_ranking_model
 from repro.obs import (Histogram, MetricsRegistry, Tracer, chrome_events,
-                       merge_trace_files, trace_payload, write_trace)
+                       merge_trace_files, span, trace_payload, write_trace)
 from repro.serve import (CoalescingBatcher, ObsPlan, PlanError,
                          PlanResolutionWarning, RankingService, ServePlan,
                          ServeRequest, ServingEngine, StageProfiler)
@@ -60,7 +62,7 @@ class TestTracer:
 
     def test_span_kinds_and_thread_stamp(self):
         t = Tracer()
-        with t.span("work", group=1):
+        with span("work", tracer=t, group=1):
             pass
         t.begin("group", track="group:0", group=1)
         t.end("group", track="group:0", group=1)
@@ -91,7 +93,7 @@ class TestTracer:
 
         def work(wid):
             for i in range(per):
-                with t.span("op", wid=wid, i=i):
+                with span("op", tracer=t, wid=wid, i=i):
                     pass
                 track = f"group:{wid}"
                 t.begin("group", track=track, group=wid * per + i)
@@ -251,7 +253,7 @@ class TestProfilerAtomicSnapshot:
 class TestExport:
     def _tracer(self):
         t = Tracer()
-        with t.span("pack", group=1):
+        with span("pack", tracer=t, group=1):
             pass
         t.begin("group", track="group:0", group=1)
         t.instant("cache_hit", user="u1")
@@ -425,6 +427,13 @@ class TestEngineTracing:
         assert {"submit", "queue_claim", "group_launch", "resolve",
                 "group", "pack", "dispatch", "collect"} <= names
         assert {"cache_hit", "cache_miss"} & names
+        # every request's own resolve instant is recorded, apart from the
+        # group's resolve_group span; idle waits stay out of the ring
+        resolves = [e for e in evs if e[1] == "resolve"]
+        assert len(resolves) == len(reqs)
+        assert {e[0] for e in resolves} == {"i"}
+        assert {e[0] for e in evs if e[1] == "resolve_group"} == {"X"}
+        assert "wait" not in names
         # linkage: every group_launch's req seqs were also submitted, and
         # its gid matches a traced group span
         submitted = {e[6]["req"] for e in evs if e[1] == "submit"}
@@ -519,3 +528,154 @@ class TestEngineTracing:
         names = [e[1] for e in eng.tracer.events()]
         assert "cache_evict" in names
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock (repro.obs.trace.span)
+# ---------------------------------------------------------------------------
+
+# every span the serving hot path opens, by the thread it runs on
+WORKER_SPANS = {"wait", "linger", "begin_coalesced", "stage1", "stage1_wait",
+                "slots", "pack", "fill", "h2d", "dispatch", "device",
+                "collect", "unpack", "resolve_group"}
+HEDGE_SPANS = {"stage2"}
+# StageProfiler phase -> the spans whose durations it totals
+PHASE_SPANS = {"stage1": ("stage1",), "pack": ("slots", "pack"),
+               "dispatch": ("dispatch",), "device": ("device",),
+               "unpack": ("unpack",), "queue_idle": ("wait",)}
+
+
+def _profiled_spans(logdir):
+    """(line index, start ns, end ns, name, args) of every ``serve.*``
+    event on the host plane of the trace written under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for li, line in enumerate(plane.lines):
+                out.extend((li, e.start_ns, e.end_ns, e.name[len("serve."):],
+                            dict(e.stats))
+                           for e in line.events
+                           if e.name.startswith("serve."))
+    return out
+
+
+class TestProfilerSpans:
+    def test_serving_spans_reach_the_profiler_clock(self, paper, tmp_path):
+        """An engine (hedging on) behind a continuous batcher, traced with
+        the Python tracer off: every span lands on the host plane with a
+        group id, children nest in their parents, and each StageProfiler
+        phase totals its spans."""
+        graph, params, user_in = paper
+        eng = ServingEngine(graph, params, plan=ServePlan().evolve(
+            batch__hedging=True, batch__continuous=True))
+        eng.score(_request(graph, user_in, 100, 9, seed=100))  # warm U=1
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            eng.profiler.snapshot(reset=True)
+            with CoalescingBatcher.from_plan(eng, eng.plan.batch) as b:
+                # a new bucket first (a blocking compile launch: the
+                # device span), then hedged launches one group at a time
+                for i, n in enumerate((200, 9, 12, 9)):
+                    b.submit(_request(graph, user_in, i, n, seed=i)
+                             ).result(timeout=120)
+                    time.sleep(0.06)            # a wait or two between
+            snap = eng.profiler.snapshot()
+        finally:
+            jax.profiler.stop_trace()
+            eng.close()
+        spans = _profiled_spans(str(tmp_path))
+        names = {s[3] for s in spans}
+        assert WORKER_SPANS | HEDGE_SPANS <= names
+        assert all(isinstance(s[4].get("group"), int) for s in spans)
+        # the worker's spans sit on one line, the hedged stage 2 elsewhere
+        worker = {s[0] for s in spans if s[3] in ("wait", "linger")}
+        assert len(worker) == 1
+        assert {s[0] for s in spans if s[3] == "stage2"} - worker
+
+        def inside(child, parent):
+            kids = [s for s in spans if s[3] == child]
+            assert kids
+            for li, s, e, _, args in kids:
+                assert any(p[0] == li and p[1] <= s and e <= p[2]
+                           and p[4]["group"] == args["group"]
+                           for p in spans if p[3] == parent), (child, s)
+
+        inside("fill", "pack")
+        inside("h2d", "pack")
+        inside("stage1_wait", "stage1")
+        inside("pack", "begin_coalesced")
+        inside("unpack", "collect")
+        for phase, members in PHASE_SPANS.items():
+            mine = [s for s in spans if s[3] in members]
+            ms = sum(e - s for _, s, e, _, _ in mine) / 1e6
+            assert snap[phase]["calls"] == len(mine) > 0, phase
+            assert abs(snap[phase]["total_ms"] - ms) <= 0.05 * ms + 0.05, (
+                phase, snap[phase]["total_ms"], ms)
+
+    def test_h2d_bytes_count_the_padded_buffers(self, paper):
+        graph, params, user_in = paper
+        eng = ServingEngine(graph, params, plan=ServePlan().evolve(
+            batch__hedging=False))
+        want = 0
+        for uid, n in ((0, 9), (1, 300)):
+            req = _request(graph, user_in, uid, n, seed=uid)
+            row = 4 + sum(np.asarray(v).dtype.itemsize
+                          * int(np.prod(np.shape(v)[1:]))
+                          for v in req.candidate_feeds.values())
+            eng.score(req)
+            want += eng._bucket(n) * row
+            assert eng.h2d_bytes == want
+        assert eng._bucket(9) > 9                # padding rows counted
+        eng.close()
+        svc = RankingService(ServePlan().evolve(batch__hedging=False))
+        svc.register("ranking", graph=graph, params=params)
+        svc.score("ranking", _request(graph, user_in, 0, 9, seed=0))
+        st = svc.stats()["scenarios"]["ranking"]
+        assert st["h2d_bytes"] == svc.engine("ranking").h2d_bytes > 0
+        svc.close()
+
+    def test_executables_carry_stable_names(self, paper):
+        graph, params, user_in = paper
+        eng = ServingEngine(graph, params, plan=ServePlan().evolve(
+            batch__hedging=False, cache__device_resident=True))
+        eng.score(_request(graph, user_in, 0, 9, seed=0))
+        exes = eng.stage2_executables()
+        assert exes and all("jit_serve_stage2" in c.as_text()
+                            for c in exes.values())
+        feeds = {k: v for k, v in _request(graph, user_in, 1, 9, seed=1)
+                 .user_feeds.items() if k in eng._stage1_inputs}
+        assert "jit_serve_stage1" in eng._stage1.lower(
+            eng.params, feeds).as_text()
+        tables = eng.device_store.tables
+        assert "jit_serve_rep_write" in eng.device_store._writer.lower(
+            tables, {k: t[:1] for k, t in tables.items()}, 0).as_text()
+        eng.close()
+
+    def test_span_costs_microseconds_without_a_session(self):
+        """With no profiler session the annotation is close to free; the
+        span still feeds the profiler phase and the ring tracer."""
+        prof, trc = StageProfiler(), Tracer()
+        with span("pack", phase="pack", profiler=prof, tracer=trc,
+                  group=7) as sp:
+            sp.set(rows=3)
+        assert prof.snapshot()["pack"]["calls"] == 1
+        assert prof.snapshot()["pack"]["total_ms"] == pytest.approx(
+            sp.seconds * 1e3)
+        (ph, name, _, dur, _, _, args), = trc.events()
+        assert (ph, name, dur, args) == ("X", "pack", sp.seconds,
+                                         {"group": 7, "rows": 3})
+        n = 20000
+        t0 = time.perf_counter()
+        for i in range(n):
+            with span("pack", group=i):
+                pass
+        assert (time.perf_counter() - t0) / n < 50e-6

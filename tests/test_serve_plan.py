@@ -576,7 +576,7 @@ class TestRankingService:
             assert sc["device_resident"] is True
             prof = sc["profile"]
             assert set(prof) == {"stage1", "pack", "dispatch", "device",
-                                 "unpack", "queue_idle", "overlap"}
+                                 "unpack", "queue_idle"}
             assert prof["pack"]["calls"] >= 1
             assert prof["pack"]["total_ms"] >= 0.0
             ds = sc["device_store"]

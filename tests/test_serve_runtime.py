@@ -575,7 +575,10 @@ class _GatedSpyEngine:
         self.groups: list[list[int]] = []
         self.gate = threading.Event()
 
-    def score_coalesced(self, reqs):
+    def new_group_id(self):
+        return None
+
+    def score_coalesced(self, reqs, gid=None):
         self.groups.append([r.user_id for r in reqs])
         if len(self.groups) == 1:
             self.gate.wait(timeout=30)
