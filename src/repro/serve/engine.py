@@ -139,6 +139,20 @@ from repro.serve.hedging import HedgedRunner, HedgePolicy
 from repro.serve.plan import ServePlan
 from repro.serve.profile import StageProfiler
 
+# Free pack staging sets kept per bucket (``_take_pack_set``). A group
+# stops taking requests once its rows reach ``max_batch``, so by rows it
+# spans at most two packs; the continuous loop holds up to
+# ``max_inflight`` (2 by default) groups in flight plus the one being
+# packed: 2 x 3 = 6 sets a bucket cover the steady state. (Small pools
+# whose packs the slot budget closes early can have more in flight; the
+# sets past the cap are then dropped at collect and allocated afresh,
+# which ``pack_buffers_allocated`` shows.) Worst-case idle host memory:
+# 6 sets at each power-of-two bucket, under twice 6 sets at max_batch,
+# i.e. 12 x max_batch x (candidate row bytes + 4); for paper-ranking at
+# 4096 rows (two 500-wide float32 feeds and the int32 user index,
+# 16.4 MB a set) that is 197 MB.
+_PACK_SETS_PER_BUCKET = 6
+
 
 @dataclasses.dataclass
 class ServeRequest:
@@ -216,6 +230,9 @@ class _InFlight:
     slots_mask: list = dataclasses.field(default_factory=list)
     #                               per pack: True = device-slot fast path
     #                               (breaker outcome accounting at collect)
+    bufsets: list = dataclasses.field(default_factory=list)
+    #                               per pack: (uidx, cand) staging set,
+    #                               back to the free list at collect
 
 
 class ServingEngine:
@@ -478,6 +495,10 @@ class ServingEngine:
         self.h2d_bytes = 0                    # candidate + user-index buffer
         #                                       bytes handed to the device,
         #                                       padding rows included
+        self.pack_buffers_allocated = 0       # pack staging sets allocated
+        self.pack_buffers_reused = 0          # packs filled into a free set
+        # bucket -> free (uidx, cand) staging sets (_take_pack_set)
+        self._pack_free: dict[int, list[tuple]] = {}
         self._inflight: list[_InFlight] = []  # launched, not yet collected
         # (U_dim, bucket) -> abstract (table, user_index, cand) arguments
         # of the first call at that signature (stage2_executables)
@@ -513,6 +534,10 @@ class ServingEngine:
                     ("cache_evictions", lambda: self.cache.evictions),
                     ("stage1_calls", lambda: self.stage1_calls),
                     ("stage2_calls", lambda: self.stage2_calls),
+                    ("pack_buffers_allocated",
+                     lambda: self.pack_buffers_allocated),
+                    ("pack_buffers_reused",
+                     lambda: self.pack_buffers_reused),
                     ("coalesced_calls", lambda: self.coalesced_calls),
                     ("pipeline_forks", lambda: self.pipeline_forks)):
                 self.metrics.gauge(name, fn)
@@ -1043,10 +1068,11 @@ class ServingEngine:
 
         # pipelined prepare+launch: launches are non-blocking (unless
         # hedging owns the dispatch), so the buffer fill + transfer of
-        # pack k+1 overlaps the device compute of pack k. Each pack owns
-        # its transfer buffers (_prepare_pack) — pack k's host->device
-        # copy may still be pending on the device stream here.
+        # pack k+1 overlaps the device compute of pack k. Each pack holds
+        # its staging set until collect (_prepare_pack) — pack k's
+        # host->device copy may still be pending on the device stream here.
         launched = []
+        bufsets = []
         try:
             for (pack_items, slot_reps, _), ds in zip(packs, dslots):
                 total = sum(n for _, _, _, n in pack_items)
@@ -1056,12 +1082,13 @@ class ServingEngine:
                           pad=bucket - total, users=len(slot_reps),
                           path="slots" if ds is not None else "restack"):
                     prep = self._prepare_pack(pack_items, slot_reps, ds,
-                                              bucket, gid)
+                                              bucket, gid, bufsets)
                 launched.append(self._launch_pack(
                     prep, on_slots=ds is not None, gid=gid))
         except BaseException:
             # never leave untracked launches behind: a later call's table
-            # write could otherwise run under them
+            # write could otherwise run under them. The call's staging
+            # sets are dropped, not recycled.
             for out, _, blocked in launched:
                 if not blocked:
                     jax.block_until_ready(out)
@@ -1070,7 +1097,8 @@ class ServingEngine:
         handle = _InFlight(reqs=reqs, infos=infos, packs=packs,
                            launched=launched, t0=t0, gid=gid,
                            track=g_track, slot=g_slot,
-                           slots_mask=[ds is not None for ds in dslots])
+                           slots_mask=[ds is not None for ds in dslots],
+                           bufsets=bufsets)
         self._inflight.append(handle)
         return handle
 
@@ -1119,7 +1147,8 @@ class ServingEngine:
         except BaseException:
             # a mid-sweep failure (injected fault, detected corruption)
             # must not leave untracked launches behind, and the group
-            # trace span must close so traces stay B/E-balanced
+            # trace span must close so traces stay B/E-balanced. The
+            # group's staging sets are dropped, not recycled.
             for out, _, blocked in handle.launched:
                 if not blocked:
                     jax.block_until_ready(out)
@@ -1183,6 +1212,13 @@ class ServingEngine:
             for ri in touched:
                 per_req_packs[ri] += 1
                 per_req_hedged[ri] += hedged
+
+        # every pack's outputs are ready, so its host->device copies have
+        # run: the staging sets may be refilled by later packs
+        for uidx_buf, cand_bufs in handle.bufsets:
+            free = self._pack_free.setdefault(len(uidx_buf), [])  # bucket
+            if len(free) < _PACK_SETS_PER_BUCKET:
+                free.append((uidx_buf, cand_bufs))
 
         wall_ms = (time.perf_counter() - handle.t0) * 1e3
         if self._group_wall_hist is not None:
@@ -1283,7 +1319,8 @@ class ServingEngine:
         return out
 
     def _prepare_pack(self, pack_items: list, slot_reps: list,
-                      dslots: list[int] | None, bucket: int, gid: int):
+                      dslots: list[int] | None, bucket: int, gid: int,
+                      bufsets: list):
         """Assemble one stage-2 call's arguments at ``bucket`` rows (spans
         ``fill``, the buffer fill, and ``h2d``, the host->device copies).
 
@@ -1291,12 +1328,10 @@ class ServingEngine:
         n_valid); ``slot_reps`` maps slot idx -> that user's rep dict;
         ``dslots`` maps slot idx -> persistent device-table slot (or None
         for the re-stacking path). Candidate rows and the user index are
-        filled into a PRIVATE per-pack host buffer — padding is one
-        masked tail write — then transferred. The buffer must be private:
-        the host->device copy executes asynchronously on the device
-        stream, behind every in-flight executable, so a shared buffer
-        refilled by a later pack races the pending copy (see the transfer
-        comment below)."""
+        filled into a host staging set private to this pack until it is
+        collected (``_take_pack_set``) — padding is one masked tail write
+        — then transferred. The set is appended to ``bufsets``, the call's
+        list that rides on its ``_InFlight`` handle."""
         self._poke("pack")
         n_slots = len(slot_reps)
 
@@ -1318,12 +1353,10 @@ class ServingEngine:
                          for k in slot_reps[0]}
             slot_ids = list(range(n_slots))
 
-        with span("fill", tracer=self.tracer, group=gid):
-            sample_chunk = pack_items[0][2]
-            uidx_buf = np.empty((bucket,), np.int32)
-            cand_bufs = {k: np.empty((bucket,) + tuple(v.shape[1:]),
-                                     v.dtype)
-                         for k, v in sample_chunk.items()}
+        with span("fill", tracer=self.tracer, group=gid) as sp:
+            uidx_buf, cand_bufs, reused = self._take_pack_set(bucket)
+            bufsets.append((uidx_buf, cand_bufs))
+            sp.set(reused=reused)
             offset = 0
             for _, slot, chunk, n in pack_items:
                 uidx_buf[offset:offset + n] = slot_ids[slot]
@@ -1341,21 +1374,24 @@ class ServingEngine:
                 for buf in cand_bufs.values():
                     buf[offset:] = buf[offset - 1]
 
-        # the buffers above are PRIVATE to this pack — nothing may mutate
-        # them after this point. jnp.array's owning host->device copy is
-        # enqueued on the device stream and executes asynchronously,
-        # behind every in-flight executable; the runtime keeps the source
-        # buffer alive until then, but it cannot protect it from being
-        # overwritten. A shared per-bucket staging buffer here let the
-        # next same-bucket pack's refill win that race under the
+        # the buffers above are PRIVATE to this pack until it is
+        # collected — nothing may mutate them before. The host->device
+        # copy is enqueued on the device stream and executes
+        # asynchronously, behind every in-flight executable; the runtime
+        # keeps the source buffer alive until then, but it cannot protect
+        # it from being overwritten. Refilling a set before its pack was
+        # collected let a later same-bucket pack win that race under the
         # continuous loop, silently swapping candidate rows between
-        # overlapped groups (caught by the bit-identity suite). One
-        # buffer allocation per pack is the price of the async dispatch.
+        # overlapped groups (caught by the bit-identity suite). Once the
+        # pack's outputs are ready its copies have run, so collect hands
+        # the set back to the free list for a later pack to refill;
+        # error paths drop it instead.
         if self._poke("transfer_copy") is CORRUPT:
             # detectable-corruption sentinel: NaN-poison the float
             # candidate buffers — NaN propagates through the stage-2
             # matmuls into the scores and is caught at collect, so a
-            # corrupted transfer is never silently served
+            # corrupted transfer is never silently served (the set may
+            # be refilled later all the same: a fill rewrites every row)
             for buf in cand_bufs.values():
                 if np.issubdtype(buf.dtype, np.floating):
                     buf.fill(np.nan)
@@ -1384,6 +1420,22 @@ class ServingEngine:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 (table, uidx_arr, cand))
         return table, uidx_arr, cand, n_slots, first_shape
+
+    def _take_pack_set(self, bucket: int) -> tuple[np.ndarray, dict, bool]:
+        """A staging set for one pack at ``bucket`` rows: the int32 user
+        index and one buffer per candidate feed, shaped from the pinned
+        ``_feed_sig``. A free set collected from an earlier pack when
+        there is one (``reused`` True), else fresh memory, which the
+        fill then page-faults in row by row."""
+        free = self._pack_free.get(bucket)
+        if free:
+            self.pack_buffers_reused += 1
+            return (*free.pop(), True)
+        self.pack_buffers_allocated += 1
+        return (np.empty((bucket,), np.int32),
+                {k: np.empty((bucket,) + shape, dtype)
+                 for k, (dtype, shape) in self._feed_sig.items()},
+                False)
 
     # -- dispatch ------------------------------------------------------------
     def _launch_pack(self, prep, *, on_slots: bool, gid: int

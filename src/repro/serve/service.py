@@ -240,6 +240,11 @@ class RankingService:
                     # candidate + user-index bytes handed to the device,
                     # padding rows included
                     "h2d_bytes": s.engine.h2d_bytes,
+                    # pack staging sets: allocated vs refilled from the
+                    # engine's free lists
+                    "pack_buffers_allocated":
+                        s.engine.pack_buffers_allocated,
+                    "pack_buffers_reused": s.engine.pack_buffers_reused,
                     "pipeline_forks": s.engine.pipeline_forks,
                     # log-bucketed distributions (repro.obs): the tail
                     # numbers an SLO is judged on, which the cumulative

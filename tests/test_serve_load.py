@@ -23,8 +23,8 @@ from repro.data.features import make_recsys_feeds
 from repro.graph.executor import init_graph_params
 from repro.models.ranking import PaperRankingConfig, build_paper_ranking_model
 from repro.serve import (AdmissionError, BatcherClosedError,
-                         CoalescingBatcher, RankingService, ServePlan,
-                         ServeRequest, ServeResult, ServingEngine)
+                         CoalescingBatcher, FaultInjected, RankingService,
+                         ServePlan, ServeRequest, ServeResult, ServingEngine)
 
 
 @pytest.fixture(scope="module")
@@ -177,6 +177,158 @@ class TestContinuousLoopIdentity:
         assert snap["queue_idle"]["calls"] >= 1
         assert set(snap) == {"stage1", "pack", "dispatch", "device",
                              "unpack", "queue_idle"}
+
+
+def _set_ids(bufsets):
+    """ids of every host buffer in a handle's staging sets."""
+    return [id(b) for uidx, cand in bufsets
+            for b in (uidx, *cand.values())]
+
+
+def _free_ids(eng):
+    return [id(b) for free in eng._pack_free.values()
+            for uidx, cand in free for b in (uidx, *cand.values())]
+
+
+class TestPackBufferPool:
+    """Pack staging sets are private to their pack until it is collected,
+    then recycled; error paths drop them; the free lists stay bounded."""
+
+    def test_serial_same_bucket_calls_reuse_one_set(self, paper):
+        graph, params, user_in = paper
+        reqs = [_request(graph, user_in, u, 90 + u, seed=u)
+                for u in range(3)]                  # all bucket 128
+        eng = ServingEngine(graph, params, plan=_plan())
+        out = []
+        for i, req in enumerate(reqs):
+            out.append(eng.score(req))
+            assert eng.pack_buffers_allocated == 1
+            assert eng.pack_buffers_reused == i
+        snap = eng.metrics.snapshot()
+        assert snap["pack_buffers_allocated"] == 1
+        assert snap["pack_buffers_reused"] == len(reqs) - 1
+        for req, got in zip(reqs, out):
+            fresh = ServingEngine(graph, params, plan=_plan())
+            assert np.array_equal(fresh.score(req).scores, got.scores)
+            assert fresh.pack_buffers_reused == 0
+
+        svc = RankingService(_plan())
+        svc.register("ranking", graph=graph, params=params)
+        for req in reqs:
+            svc.score("ranking", req)
+        st = svc.stats()["scenarios"]["ranking"]
+        assert st["pack_buffers_allocated"] == 1
+        assert st["pack_buffers_reused"] == len(reqs) - 1
+        svc.close()
+
+    def test_overlapped_packs_never_share_a_staging_set(self, paper):
+        graph, params, user_in = paper
+        plan = ServePlan().evolve(batch__max_batch=1024,
+                                  batch__hedging=False,
+                                  cache__device_resident=False)
+        eng = ServingEngine(graph, params, plan=plan)
+        big = _request(graph, user_in, 0, 3 * 1024, seed=0)
+        victim = _request(graph, user_in, 1, 1000, seed=1)    # bucket 1024
+        attacker = _request(graph, user_in, 2, 900, seed=2)   # bucket 1024
+        ref = [eng.score(r) for r in (big, victim, attacker)]
+        for _ in range(3):
+            h1 = eng.begin_coalesced([big, victim])
+            h2 = eng.begin_coalesced([attacker])
+            held = _set_ids(h1.bufsets) + _set_ids(h2.bufsets)
+            assert len(held) == len(set(held))      # no set held twice
+            assert not set(held) & set(_free_ids(eng))
+            out = eng.collect(h1) + eng.collect(h2)
+            for got, want in zip(out, ref):
+                assert np.array_equal(got.scores, want.scores)
+        # the serial calls reuse 2 of their 5 packs' sets; the first
+        # overlap (5 packs in flight) reuses the 3 sets and allocates 2;
+        # the next two overlaps reuse all 5
+        assert eng.pack_buffers_allocated == 5
+        assert eng.pack_buffers_reused == 2 + 3 + 2 * 5
+
+    @pytest.mark.parametrize("site", ["collect:error", "collect:corrupt",
+                                      "stage2_dispatch:error"])
+    def test_failed_group_drops_its_sets(self, paper, site):
+        graph, params, user_in = paper
+        req = _request(graph, user_in, 0, 100, seed=0)
+        want = ServingEngine(graph, params, plan=_plan()).score(req).scores
+        eng = ServingEngine(graph, params, plan=_plan(
+            ft__inject=True, ft__sites=(f"{site}:after=1,count=1",)))
+        assert np.array_equal(eng.score_coalesced([req])[0].scores, want)
+        pooled = set(_free_ids(eng))
+        h = eng.begin_coalesced([req]) if site.startswith("collect") \
+            else None
+        with pytest.raises(FaultInjected):
+            if h is None:
+                eng.begin_coalesced([req])
+            else:
+                eng.collect(h)
+        assert eng.pack_buffers_reused == 1         # the failed pack's set
+        assert eng._pack_free[128] == []            # ... is not returned
+        for _ in range(2):
+            assert np.array_equal(eng.score_coalesced([req])[0].scores,
+                                  want)
+        assert eng.pack_buffers_allocated == 2
+        assert not pooled & set(_free_ids(eng))
+
+    def test_poisoned_set_is_dropped_and_refill_rewrites_every_row(
+            self, paper):
+        graph, params, user_in = paper
+        req = _request(graph, user_in, 0, 100, seed=0)
+        want = ServingEngine(graph, params, plan=_plan()).score(req).scores
+        eng = ServingEngine(graph, params, plan=_plan(
+            ft__inject=True, ft__sites=("transfer_copy:corrupt:after=1,"
+                                        "count=1",)))
+        eng.score_coalesced([req])
+        poisoned = set(_free_ids(eng))
+        with pytest.raises(FaultInjected, match="corrupt"):
+            eng.score_coalesced([req])              # NaN-poisoned transfer
+        assert eng.corruptions_detected == 1
+        got = eng.score_coalesced([req])[0].scores
+        assert np.isfinite(got).all() and np.array_equal(got, want)
+        assert not poisoned & set(_free_ids(eng))
+        # a set that comes back NaN-filled is rewritten in full by its
+        # next fill, padding rows included
+        for cand in (c for _, c in eng._pack_free[128]):
+            for buf in cand.values():
+                buf.fill(np.nan)
+        reused = eng.pack_buffers_reused
+        got = eng.score_coalesced([req])[0].scores
+        assert eng.pack_buffers_reused == reused + 1
+        assert np.isfinite(got).all() and np.array_equal(got, want)
+
+    def test_free_lists_stay_under_their_cap(self, paper, monkeypatch):
+        from repro.serve import engine as engine_mod
+        cap = engine_mod._PACK_SETS_PER_BUCKET
+        graph, params, user_in = paper
+        eng = ServingEngine(graph, params, plan=_plan())
+        # more same-bucket packs in flight than the cap: the surplus is
+        # dropped at collect
+        hs = [eng.begin_coalesced([_request(graph, user_in, 0, 100,
+                                            seed=s)])
+              for s in range(cap + 2)]
+        for h in hs:
+            eng.collect(h)
+        assert eng.pack_buffers_allocated == cap + 2
+        assert len(eng._pack_free[128]) == cap
+
+        longest = []
+        collect_body = eng._collect_body
+
+        def watched(handle):
+            out = collect_body(handle)
+            longest.append(max(map(len, eng._pack_free.values())))
+            return out
+
+        monkeypatch.setattr(eng, "_collect_body", watched)
+        reqs = [_request(graph, user_in, i % 5, 20 + 37 * (i % 7), seed=i)
+                for i in range(60)]             # buckets 128 to 256
+        with CoalescingBatcher(eng, linger_ms=1.0, max_coalesce=3,
+                               continuous=True, max_inflight=4) as b:
+            for f in [b.submit(r) for r in reqs]:
+                f.result(timeout=120)
+        assert len(longest) == b.batches and max(longest) <= cap
+        assert eng.pack_buffers_reused > eng.pack_buffers_allocated
 
 
 class _GatedResultEngine:
