@@ -8,22 +8,17 @@ import jax.numpy as jnp
 import pytest
 
 from chipbench import model, run
-from chipbench.tests.conftest import TEST_ONLY_CELL
+from chipbench.tests.conftest import CELLS, CONFIGS, run_args, zipf_test_cell
 from chipbench.tools.calibrate import CONTROLS
 
 
-def _args(cell, seed=2**31 + 77):
-    return run.parse_args(["--workload", cell, "--seed", str(seed),
-                           "--seconds", "1", "--trace", "0"])
-
-
-@pytest.mark.parametrize("cell", ["paper-ranking.cold-sat"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_the_control_fails_the_limit_that_the_program_passes(smoke_root,
                                                               cell):
     """The float8 reference put in the program's place is judged by the same
     comparison and verdict as the served answers, and comes out not
     correct where the program comes out correct."""
-    res = run.run_cell(_args(cell), root=smoke_root, require_tpu=False,
+    res = run.run_cell(run_args(cell), root=smoke_root, require_tpu=False,
                        controls={"fp8_e4m3": CONTROLS["fp8_e4m3"]})
     limit = res["checks"]["max_abs_err"]["limit"]
     assert res["correct"], res["checks"]
@@ -93,10 +88,10 @@ FAULTS = {"answer_altered": _alter_answers,
 
 
 @pytest.mark.parametrize(("fault", "cell"), [
-    ("answer_altered", "paper-ranking.cold-sat"),
-    ("pack_rows_shifted", "paper-ranking.cold-sat"),
-    # the committed cell serves no cache hit; the test-only Zipf cell does
-    ("hit_serves_another_user", TEST_ONLY_CELL)])
+    *((f, c) for f in ("answer_altered", "pack_rows_shifted") for c in CELLS),
+    # the committed cells serve no cache hit; each configuration's
+    # test-only Zipf cell does
+    *(("hit_serves_another_user", zipf_test_cell(c)) for c in CONFIGS)])
 def test_an_answer_altered_where_it_is_produced_is_not_correct(
         smoke_root, monkeypatch, fault, cell):
     """A run whose timed path is broken underneath comes out not correct,
@@ -104,7 +99,7 @@ def test_an_answer_altered_where_it_is_produced_is_not_correct(
     engine produces it, candidate rows misplaced in a stage-2 pack, and a
     rep-cache hit that serves another user's reps."""
     FAULTS[fault](monkeypatch)
-    res = run.run_cell(_args(cell), root=smoke_root, require_tpu=False)
+    res = run.run_cell(run_args(cell), root=smoke_root, require_tpu=False)
     assert not res["correct"]
     assert res["checks"]["max_abs_err"]["value"] > \
         res["checks"]["max_abs_err"]["limit"]
@@ -112,12 +107,14 @@ def test_an_answer_altered_where_it_is_produced_is_not_correct(
 
 
 def test_the_test_only_cell_serves_cache_hits(smoke_root):
-    """The cache-hit fault has hits to corrupt: the test-only cell's
-    sample holds cache hits, and the unbroken run is correct."""
-    res = run.run_cell(_args(TEST_ONLY_CELL), root=smoke_root,
-                       require_tpu=False)
-    assert res["correct"], res["checks"]
-    assert res["reading"]["hits"] > 0
+    """The cache-hit fault has hits to corrupt: each configuration's
+    test-only cell's sample holds cache hits, and the unbroken run is
+    correct."""
+    for config in CONFIGS:
+        res = run.run_cell(run_args(zipf_test_cell(config)),
+                           root=smoke_root, require_tpu=False)
+        assert res["correct"], (config, res["checks"])
+        assert res["reading"]["hits"] > 0, config
 
 
 def test_controls_round_the_operands():

@@ -68,6 +68,12 @@ def test_every_cell_of_the_benchmark_has_its_files():
                                                       "end_to_end")}
         assert "setup_s" in names and len(names) >= 2
         assert spec.cell_metrics(b, w["name"], "per_layer")
+    # each configuration's reference and its smoke size for the CPU checks
+    for c in b["configs"]:
+        for rel in (f"reference/{c['name']}.py",
+                    f"tests/smoke/{c['name']}.json"):
+            path = spec.bench_dir(spec.ROOT, b) / rel
+            assert path.is_file(), f"configuration {c['name']!r} lacks {path}"
 
 
 def test_the_run_refuses_a_device_that_is_not_a_tpu(capsys):

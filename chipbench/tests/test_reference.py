@@ -7,11 +7,11 @@ import numpy as np
 import pytest
 
 from chipbench import model, spec
-from chipbench.tests.conftest import smoke_config
+from chipbench.tests.conftest import CONFIGS, smoke_config
 from chipbench.traffic import Traffic
 
 
-@pytest.mark.parametrize("name", ["paper-ranking"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_reference_agrees_with_the_program_reference_scorer(name):
     from repro.serve import ServeRequest
     from repro.serve.reference import SCORE_TOL, ReferenceScorer
@@ -46,14 +46,15 @@ def test_reference_agrees_with_the_program_reference_scorer(name):
 
 
 def test_weights_are_made_from_the_seed():
-    cfg = smoke_config("paper-ranking")
-    ref = spec.load_reference(spec.ROOT, spec.load_benchmark(),
-                              "paper-ranking")
-    shapes = ref.param_shapes(cfg)
-    a = model.make_weights(shapes, cfg["init"], 123)
-    b = model.make_weights(shapes, cfg["init"], 123)
-    c = model.make_weights(shapes, cfg["init"], 124)
-    for x, y, z in zip(jax.tree.leaves(a), jax.tree.leaves(b),
-                       jax.tree.leaves(c)):
-        np.testing.assert_array_equal(x, y)
-        assert not np.array_equal(x, z)
+    bench = spec.load_benchmark()
+    for name in CONFIGS:
+        cfg = smoke_config(name)
+        shapes = spec.load_reference(spec.ROOT, bench,
+                                     name).param_shapes(cfg)
+        a = model.make_weights(shapes, cfg["init"], 123)
+        b = model.make_weights(shapes, cfg["init"], 123)
+        c = model.make_weights(shapes, cfg["init"], 124)
+        for x, y, z in zip(jax.tree.leaves(a), jax.tree.leaves(b),
+                           jax.tree.leaves(c)):
+            np.testing.assert_array_equal(x, y, err_msg=name)
+            assert not np.array_equal(x, z), name
