@@ -466,9 +466,8 @@ class Executor:
                     h = dense_apply(p[f"layer_{li}"], h)
                     if li < nlayers - 1:
                         h = jax.nn.relu(h)
-                scores = h[..., 0]                      # (B, L)
-                scores = jnp.where(mask, scores, -1e30)
-                w = jax.nn.softmax(scores, axis=-1)
+                # Eq. (3): the unit's outputs are the pooling weights
+                w = jnp.where(mask, h[..., 0], 0.0)     # (B, L)
                 if k_stacked:
                     return self._gather_einsum("bl,uld->bd", w, keys, uidx)
                 if keys.shape[0] == 1 and w.shape[0] != 1:

@@ -79,16 +79,17 @@ def target_attention(
     mask: Array,       # (B, L) or (1, L) bool valid positions
     mlp_apply,         # callable(x: (..., 4D)) -> (..., 1) attention MLP
 ) -> Array:
-    """DIN local-activation unit: score each history item against the target
-    via an MLP over [key, query, key-query, key*query]; weighted sum-pool."""
+    """DIN local-activation unit (arXiv:1706.06978, Eq. 3): an MLP over
+    [key, query, key-query, key*query] gives each history item's weight
+    against the target, and the keys are sum-pooled by those weights. The
+    weights are not normalized (no softmax, so they need not sum to 1: the
+    paper keeps the strength of a user's interest); a masked slot weighs 0."""
     if keys.shape[0] == 1 and query.shape[0] != 1:
         keys = jnp.broadcast_to(keys, (query.shape[0],) + keys.shape[1:])
         mask = jnp.broadcast_to(mask, (query.shape[0],) + mask.shape[1:])
     q = jnp.broadcast_to(query[:, None, :], keys.shape)  # (B, L, D)
     feats = jnp.concatenate([keys, q, keys - q, keys * q], axis=-1)
-    scores = mlp_apply(feats)[..., 0]  # (B, L)
-    scores = jnp.where(mask, scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
+    w = jnp.where(mask, mlp_apply(feats)[..., 0], 0.0)  # (B, L)
     return jnp.einsum("bl,bld->bd", w, keys)
 
 

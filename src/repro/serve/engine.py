@@ -495,6 +495,8 @@ class ServingEngine:
         self.h2d_bytes = 0                    # candidate + user-index buffer
         #                                       bytes handed to the device,
         #                                       padding rows included
+        self.rep_stack_bytes = 0              # re-stacked user-rep table
+        #                                       bytes, padding slots included
         self.pack_buffers_allocated = 0       # pack staging sets allocated
         self.pack_buffers_reused = 0          # packs filled into a free set
         # bucket -> free (uidx, cand) staging sets (_take_pack_set)
@@ -534,6 +536,7 @@ class ServingEngine:
                     ("cache_evictions", lambda: self.cache.evictions),
                     ("stage1_calls", lambda: self.stage1_calls),
                     ("stage2_calls", lambda: self.stage2_calls),
+                    ("rep_stack_bytes", lambda: self.rep_stack_bytes),
                     ("pack_buffers_allocated",
                      lambda: self.pack_buffers_allocated),
                     ("pack_buffers_reused",
@@ -1322,6 +1325,7 @@ class ServingEngine:
                       dslots: list[int] | None, bucket: int, gid: int,
                       bufsets: list):
         """Assemble one stage-2 call's arguments at ``bucket`` rows (spans
+        ``stack``, the re-stack of several users' reps into one table,
         ``fill``, the buffer fill, and ``h2d``, the host->device copies).
 
         ``pack_items`` is a list of (req idx, slot idx, cand chunk,
@@ -1349,8 +1353,13 @@ class ServingEngine:
                 table = dict(slot_reps[0])
             else:
                 padded = slot_reps + [slot_reps[0]] * (u_dim - n_slots)
-                table = {k: jnp.concatenate([r[k] for r in padded], axis=0)
-                         for k in slot_reps[0]}
+                nbytes = u_dim * sum(v.nbytes for v in slot_reps[0].values())
+                with span("stack", tracer=self.tracer, group=gid,
+                          users=n_slots, bytes=nbytes):
+                    table = {k: jnp.concatenate([r[k] for r in padded],
+                                                axis=0)
+                             for k in slot_reps[0]}
+                self.rep_stack_bytes += nbytes
             slot_ids = list(range(n_slots))
 
         with span("fill", tracer=self.tracer, group=gid) as sp:

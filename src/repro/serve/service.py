@@ -240,6 +240,9 @@ class RankingService:
                     # candidate + user-index bytes handed to the device,
                     # padding rows included
                     "h2d_bytes": s.engine.h2d_bytes,
+                    # user-rep table bytes re-stacked on the device for
+                    # packs of several users, padding slots included
+                    "rep_stack_bytes": s.engine.rep_stack_bytes,
                     # pack staging sets: allocated vs refilled from the
                     # engine's free lists
                     "pack_buffers_allocated":

@@ -643,6 +643,41 @@ class TestProfilerSpans:
         assert st["h2d_bytes"] == svc.engine("ranking").h2d_bytes > 0
         svc.close()
 
+    def test_rep_stack_bytes_count_the_padded_table(self, paper):
+        """A coalesced call of several users re-stacks their reps into one
+        table inside the pack span: a ``stack`` span with ``users`` and
+        ``bytes``, and ``rep_stack_bytes`` grows by the table's bytes, the
+        slots padding the user count to a power of two included."""
+        graph, params, user_in = paper
+        eng = ServingEngine(graph, params, plan=TRACE_PLAN)
+        eng.score(_request(graph, user_in, 9, 9, seed=9))   # one user: none
+        assert eng.rep_stack_bytes == 0
+        want = 0
+        for uids, u_dim in (((0, 1), 2), ((2, 3, 4), 4)):
+            eng.score_coalesced([_request(graph, user_in, u, 9, seed=u)
+                                 for u in uids])
+            row = sum(v.nbytes for v in eng.cache.get((uids[0], 0)).values())
+            want += u_dim * row
+            assert eng.rep_stack_bytes == want
+        evs = eng.tracer.events()
+        stacks = [e for e in evs if e[1] == "stack"]
+        assert [(e[6]["users"], e[6]["bytes"]) for e in stacks] == [
+            (2, 2 * row), (3, 4 * row)]
+        packs = [e for e in evs if e[1] == "pack"]
+        for _, _, ts, dur, _, _, args in stacks:
+            assert any(p[6]["group"] == args["group"] and p[2] <= ts
+                       and ts + dur <= p[2] + p[3] for p in packs)
+        assert eng.metrics.snapshot()["rep_stack_bytes"] == want
+        eng.close()
+        svc = RankingService(TRACE_PLAN)
+        svc.register("ranking", graph=graph, params=params)
+        svc.engine("ranking").score_coalesced(
+            [_request(graph, user_in, u, 9, seed=u) for u in (0, 1)])
+        st = svc.stats()["scenarios"]["ranking"]
+        assert st["rep_stack_bytes"] == svc.engine(
+            "ranking").rep_stack_bytes > 0
+        svc.close()
+
     def test_executables_carry_stable_names(self, paper):
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, plan=ServePlan().evolve(
