@@ -153,6 +153,9 @@ from repro.serve.profile import StageProfiler
 # 16.4 MB a set) that is 197 MB.
 _PACK_SETS_PER_BUCKET = 6
 
+# HedgedRunner counters surfaced by ``hedge_counters`` and the registry
+_HEDGE_COUNTERS = ("hedges_launched", "hedge_wins", "pool_exhausted")
+
 
 @dataclasses.dataclass
 class ServeRequest:
@@ -487,6 +490,10 @@ class ServingEngine:
 
         self.stage1_calls = 0                 # trace counter for the split test
         self.stage2_calls = 0                 # total row-wise dispatches
+        self.stage2_blocking_launches = 0     # stage-2 launches that waited
+        #                                       for their result (a hedging
+        #                                       engine's: hedged runs and
+        #                                       compile calls)
         self.coalesced_calls = 0              # dispatches mixing >1 user slot
         self.pipeline_forks = 0               # copy-on-write table forks
         #                                       (begin_coalesced needed a row
@@ -536,6 +543,8 @@ class ServingEngine:
                     ("cache_evictions", lambda: self.cache.evictions),
                     ("stage1_calls", lambda: self.stage1_calls),
                     ("stage2_calls", lambda: self.stage2_calls),
+                    ("stage2_blocking_launches",
+                     lambda: self.stage2_blocking_launches),
                     ("rep_stack_bytes", lambda: self.rep_stack_bytes),
                     ("pack_buffers_allocated",
                      lambda: self.pack_buffers_allocated),
@@ -554,6 +563,10 @@ class ServingEngine:
         self.hedging = hedging
         self._hedged = (HedgedRunner(self._dispatch, self.hedge_policy)
                         if hedging else None)
+        if self.metrics is not None and self._hedged is not None:
+            for name in _HEDGE_COUNTERS:
+                self.metrics.gauge(
+                    name, lambda name=name: getattr(self._hedged, name))
 
         # -- fault tolerance (plan.ft): deterministic injection + the
         # stage-2 circuit breaker + device-tier quarantine. Off by default:
@@ -1450,11 +1463,13 @@ class ServingEngine:
     def _launch_pack(self, prep, *, on_slots: bool, gid: int
                      ) -> tuple[dict, int, bool]:
         """Launch one prepared pack. Returns (outputs, hedged count,
-        blocked) — ``blocked`` marks results already materialized (the
-        hedging path owns its own latency observation and must see final
-        latencies, so it stays blocking). ``on_slots`` marks the
-        device-resident fast path: a failed launch there counts toward
-        the circuit breaker."""
+        blocked) — ``blocked`` marks results already materialized: a
+        hedging engine waits for every launch (the hedge owns its own
+        latency observation and must see final latencies), and
+        ``stage2_blocking_launches`` counts those waits. Without hedging
+        the launch only enqueues, and ``collect`` waits. ``on_slots``
+        marks the device-resident fast path: a failed launch there counts
+        toward the circuit breaker."""
         table, uidx_arr, cand, n_slots, first_shape = prep
         self.stage2_calls += 1
         if n_slots > 1:
@@ -1482,16 +1497,17 @@ class ServingEngine:
                     if on_slots and self.breaker is not None:
                         self.breaker.record_failure()
                     raise
+        if self._hedged is None:
+            return out, 0, False
+        self.stage2_blocking_launches += 1
         if hedge:
             return out, int(outcome.hedged), True
-        if self._hedged is not None:
-            # compile call of a hedging engine: block here (latency would
-            # poison the policy window, so it is not observed either)
-            with span("device", phase="device", tracer=trc, profiler=prof,
-                      group=gid):
-                jax.block_until_ready(out)
-            return out, 0, True
-        return out, 0, False
+        # compile call of a hedging engine: block here (latency would
+        # poison the policy window, so it is not observed either)
+        with span("device", phase="device", tracer=trc, profiler=prof,
+                  group=gid):
+            jax.block_until_ready(out)
+        return out, 0, True
 
     def _execute(self, params, table, uidx, cand):
         """Enqueue stage 2 (+ optional compressed gather) WITHOUT blocking:
@@ -1510,6 +1526,14 @@ class ServingEngine:
             out = self._execute(params, table, uidx, cand)
             jax.block_until_ready(out)
         return out
+
+    def hedge_counters(self) -> dict[str, int | None]:
+        """The hedge runner's duplicates launched, duplicates that won,
+        and calls denied a pool worker; None each where the engine does
+        not hedge."""
+        return {name: (None if self._hedged is None
+                       else getattr(self._hedged, name))
+                for name in _HEDGE_COUNTERS}
 
     def invalidate_user(self, user_id: int) -> None:
         self.cache.invalidate_user(self._scoped_uid(user_id))

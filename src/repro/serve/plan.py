@@ -810,10 +810,15 @@ PRESETS: dict[str, ServePlan] = {
     "vanilla": ServePlan(graph=GraphPlan(mode="vani")),
     "uoi": ServePlan(graph=GraphPlan(mode="uoi")),
     # everything the Pallas path offers: fused mari_dense with the
-    # kernel-side rep-table gather + gather-at-load decomposed attention
+    # kernel-side rep-table gather + gather-at-load decomposed attention.
+    # Hedging off: on one chip a duplicate queues on the same device
+    # stream behind its primary and cannot finish first, while the hedged
+    # launch blocks the worker until the pack is done; unhedged, a launch
+    # returns at once and the host packs the next pack meanwhile
     "tpu": ServePlan(graph=GraphPlan(mode="mari", reparam_attention=True),
                      kernel=KernelPlan(use_pallas=True, kernel_gather=True,
-                                       gather_attention=True)),
+                                       gather_attention=True),
+                     batch=BatchPlan(hedging=False)),
     # candidate-axis sharding on the 'cand' mesh; hedging off because the
     # multi-process SPMD schedule cannot tolerate per-process duplicates
     "distributed": ServePlan(shard=ShardPlan(shard_candidates=True),
