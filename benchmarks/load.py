@@ -126,8 +126,8 @@ def build_plan(preset: str, variant: str, args):
     from repro.serve import ServePlan
     plan = ServePlan.preset(preset).evolve(
         batch__max_batch=args.max_batch, batch__min_bucket=args.B,
-        batch__hedging=False, batch__linger_ms=args.linger_ms,
-        batch__admission=True, batch__shed_queue_depth=args.shed_depth,
+        batch__linger_ms=args.linger_ms, batch__admission=True,
+        batch__shed_queue_depth=args.shed_depth,
         batch__degrade_queue_depth=args.degrade_depth,
         batch__degrade_frac=0.5, batch__deadline_headroom_ms=0.25,
         cache__device_resident=True, cache__device_slots=args.device_slots)
@@ -395,8 +395,8 @@ def build_chaos_plan(args):
     from repro.serve import ServePlan
     plan = ServePlan.preset("paper").evolve(
         batch__max_batch=args.max_batch, batch__min_bucket=args.B,
-        batch__hedging=False, batch__linger_ms=args.linger_ms,
-        cache__device_resident=True, cache__device_slots=args.device_slots,
+        batch__linger_ms=args.linger_ms, cache__device_resident=True,
+        cache__device_slots=args.device_slots,
         ft__inject=True, ft__seed=args.seed, ft__sites=CHAOS_SITES,
         ft__retries=4, ft__retry_backoff_ms=2.0,
         ft__breaker_failures=3, ft__breaker_cooldown_ms=150.0,

@@ -61,7 +61,7 @@ def _build(seed: int = 0):
 
 def _plan(cold_bytes: int | None):
     from repro.serve import ServePlan
-    ev = dict(batch__hedging=False, batch__linger_ms=0.0,
+    ev = dict(batch__linger_ms=0.0,
               cache__max_cached_users=4096,
               cache__device_resident=True, cache__device_slots=256,
               mem__cold_tier=True, mem__warm_batch=4096)
@@ -186,8 +186,7 @@ def main() -> int:
     # full recompute of the exact same executable family
     from repro.serve import ServePlan, ServingEngine
     ref = ServingEngine(graph, params, plan=ServePlan.preset("paper").evolve(
-        cache__cache_user_reps=False, batch__hedging=False,
-        batch__linger_ms=0.0))
+        cache__cache_user_reps=False, batch__linger_ms=0.0))
 
     rows = []
     points = {}

@@ -238,17 +238,14 @@ def bench_serve(scale: float = 0.12, B: int = 2000, iters: int = 15,
     cand = {k: v for k, v in feeds.items() if k not in user_in}
 
     # rows are keyed by plan preset: each mode IS a preset's paradigm
-    # (vanilla/uoi/paper), evolved with the bench's row budget and hedging
-    # off — duplicate executions on this shared CPU would contaminate the
-    # latency/throughput rows the trajectory tracks. Two-stage modes turn
-    # the device-resident rep tier on (the dispatch-overhead fight this
-    # bench referees). The exact plan rides along in every JSON row
-    # (provenance — incl. ``cache.device_resident``).
+    # (vanilla/uoi/paper), evolved with the bench's row budget. Two-stage
+    # modes turn the device-resident rep tier on (the dispatch-overhead
+    # fight this bench referees). The exact plan rides along in every JSON
+    # row (provenance — incl. ``cache.device_resident``).
     presets = {"vani": "vanilla", "uoi": "uoi", "mari": "paper"}
     modes = {}
     for mode in ("vani", "uoi", "mari"):
-        plan = ServePlan.preset(presets[mode]).evolve(
-            batch__max_batch=4096, batch__hedging=False)
+        plan = ServePlan.preset(presets[mode]).evolve(batch__max_batch=4096)
         if mode != "vani":
             plan = plan.evolve(cache__device_resident=True)
         graph, params = graphs["thin" if mode == "vani" else "heavy"]
@@ -533,7 +530,7 @@ def bench_attn(B: int = 2000, users: int = 8, iters: int = 5):
     for gather in (False, True):
         plans[gather] = ServePlan.preset("tpu").evolve(
             kernel__kernel_gather=False, kernel__gather_attention=gather,
-            batch__max_batch=4096, batch__hedging=False)
+            batch__max_batch=4096)
         eng = ServingEngine(graph, params, plan=plans[gather])
         reps = []
         for uid in range(users):

@@ -121,12 +121,11 @@ def main():
                   f"{len(eng.conversion.rewrites)} matmuls")
         if eng.two_stage:
             print(f"[{mode}] {eng.split.summary()}")
-        lats, hits, hedges = [], 0, 0
+        lats, hits = [], 0
         for req in request_stream(jax.random.PRNGKey(42)):
             res = eng.score(req)
             lats.append(res.latency_ms)
             hits += res.user_cache_hit
-            hedges += res.hedged
         check([req], [res], mode)     # the last request of the stream
         lats = np.asarray(lats[2:])   # drop warm-up/compile
         extra = (f"  stage1_runs={eng.stage1_calls}"
@@ -135,19 +134,15 @@ def main():
         print(f"[{mode}] avg={lats.mean():7.2f}ms  "
               f"p50={np.percentile(lats, 50):7.2f}ms  "
               f"p99={np.percentile(lats, 99):7.2f}ms  "
-              f"user_cache_hits={hits}/{args.requests}  "
-              f"hedged={hedges}{extra}")
+              f"user_cache_hits={hits}/{args.requests}{extra}")
         eng.close()
     print(f"all modes within {tol} of the float32 reference ✓")
 
     # ---- part 2: async multi-user stream through the coalescing batcher ----
     print(f"\n-- async coalescing (mari): multi-user burst, ragged pools, "
           f"linger={args.linger_ms}ms --")
-    # hedging off for the timed comparison: duplicate executions on a
-    # shared CPU would contaminate the seq-vs-coalesced req/s numbers
     eng = ServingEngine(graph, params, plan=base_plan.evolve(
-        graph__mode="mari", batch__hedging=False,
-        obs__trace=args.trace is not None))
+        graph__mode="mari", obs__trace=args.trace is not None))
     rng = np.random.default_rng(0)
     keys = jax.random.split(jax.random.PRNGKey(7), args.requests)
     burst = [make_request(r, keys[r],
@@ -194,7 +189,7 @@ def main():
     # tier: shed best_effort beyond 8 queued, halve its candidate pool
     # beyond 4 queued; deadline-tagged requests are exempt from both
     over_plan = base_plan.evolve(
-        graph__mode="mari", batch__hedging=False, batch__continuous=True,
+        graph__mode="mari", batch__continuous=True,
         batch__admission=True, batch__shed_queue_depth=8,
         batch__degrade_queue_depth=4, batch__degrade_frac=0.5,
         batch__linger_ms=args.linger_ms,
@@ -242,8 +237,8 @@ def main():
     # hot LRU deliberately smaller than the user universe: users live ONLY
     # in the host-RAM arena until the promotion worker sees repeat traffic
     mem_eng = ServingEngine(graph, params, plan=base_plan.evolve(
-        graph__mode="mari", batch__hedging=False,
-        cache__max_cached_users=2, mem__cold_tier=True))
+        graph__mode="mari", cache__max_cached_users=2,
+        mem__cold_tier=True))
     warmed = mem_eng.warm(sorted(user_feeds.items()))
     warm_results = [mem_eng.score(r) for r in burst]
     hot = sum(r.user_cache_hit for r in warm_results)

@@ -67,16 +67,15 @@ MODES = ("vani", "uoi", "mari")
 
 def build_plan(args) -> ServePlan:
     """The fleet's serving plan: an optional ``--plan`` JSON file with the
-    runner's operating requirements layered on top — candidate-axis
-    sharding on (that is what this runner exists to drive) and hedging off
-    (per-process duplicates would desynchronize the SPMD schedule).
+    runner's operating requirement layered on top — candidate-axis
+    sharding on (that is what this runner exists to drive).
 
     Flag overrides beat the plan file only when EXPLICITLY given; without
     a plan file the runner's own bench-sized defaults apply. A plan file's
     ``max_batch``/``min_bucket``/``compress_scores`` therefore survive
     unless the caller asks otherwise."""
     base = ServePlan.load(args.plan) if args.plan else ServePlan()
-    over = {"batch__hedging": False}
+    over = {}
     if not base.shard.shard_candidates:
         # force sharding ON, but keep a plan file's explicit shard COUNT
         over["shard__shard_candidates"] = True

@@ -15,9 +15,3 @@ from repro.ft.recovery import (  # noqa: F401
     RetryPolicy,
 )
 
-
-def __getattr__(name):            # lazy back-compat re-export (PEP 562):
-    if name == "HedgePolicy":     # keeps `import repro.ft` free of the
-        from repro.serve.hedging import HedgePolicy  # serve/JAX stack
-        return HedgePolicy
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

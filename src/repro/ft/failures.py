@@ -8,24 +8,17 @@ the transport (who pings whom) is an injectable clock/callback).
 * plan_elastic_remesh — given surviving device count, picks the largest
   valid (data, model) mesh that preserves the TP degree (model axis is
   topology-constrained; DP shrinks), and reports the batch re-split.
-* HedgePolicy — straggler mitigation for serving. The policy (rolling-p99
-  deadline) and its real executor (duplicate execution, first result wins)
-  now live in ``repro.serve.hedging``; the name is re-exported here for
-  backward compatibility — lazily, so this module stays importable without
-  pulling the serve/JAX stack into stdlib-only control-plane processes.
+* HedgePolicy — straggler mitigation for serving: duplicate a candidate
+  mini-batch onto a second replica once its latency exceeds the rolling
+  p99; first responder wins (standard tail-at-scale hedging).
 """
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
+from collections import deque
 from typing import Callable
-
-
-def __getattr__(name):            # lazy back-compat re-export (PEP 562)
-    if name == "HedgePolicy":
-        from repro.serve.hedging import HedgePolicy
-        return HedgePolicy
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 class HeartbeatMonitor:
@@ -104,3 +97,36 @@ def plan_elastic_remesh(old_shape: tuple[int, ...], axes: tuple[str, ...],
         dropped_devices=old_dp * model - surviving_devices,
         global_batch_scale=new_dp / old_dp,
         notes=f"DP {old_dp}->{new_dp}, TP preserved at {model}")
+
+
+class HedgePolicy:
+    """Rolling-quantile hedging decision (tail-at-scale).
+
+    Thread-safe: ``observe`` runs on whichever thread finishes a chunk
+    while ``hedge_deadline_ms`` reads the window from the dispatcher —
+    the window is snapshotted under a lock, so the sort never sees a
+    deque mutating beneath it.
+    """
+
+    def __init__(self, quantile: float = 0.99, window: int = 512,
+                 min_hedge_ms: float = 5.0):
+        self.q = quantile
+        self.lat = deque(maxlen=window)
+        self.min_hedge_ms = min_hedge_ms
+        self._lock = threading.Lock()
+
+    def observe(self, latency_ms: float) -> None:
+        with self._lock:
+            self.lat.append(latency_ms)
+
+    def hedge_deadline_ms(self) -> float:
+        with self._lock:
+            xs = list(self.lat)
+        if len(xs) < 16:
+            return self.min_hedge_ms * 10
+        xs.sort()
+        idx = min(len(xs) - 1, int(self.q * len(xs)))
+        return max(xs[idx], self.min_hedge_ms)
+
+    def should_hedge(self, elapsed_ms: float) -> bool:
+        return elapsed_ms >= self.hedge_deadline_ms()

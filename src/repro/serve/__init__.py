@@ -35,8 +35,6 @@ grown into an async, multi-user subsystem:
   on the JAX profiler's clock as a ``serve.<name>`` annotation: an
   operator who captures a profile (``jax.profiler.start_server`` +
   XProf/Perfetto) sees the ``serve.*`` spans beside the device's ops.
-* ``hedging`` — ``HedgePolicy`` (rolling-p99 decision) + ``HedgedRunner``
-  (real duplicate execution of straggling chunks, first result wins).
 * ``plan``    — ``ServePlan``: the frozen, validated, JSON-serializable
   serving configuration (nested Graph/Kernel/Batch/Shard/Cache sections,
   cross-field validation with a documented resolution table, named
@@ -97,7 +95,6 @@ from repro.serve.engine import (  # noqa: F401
     ServeResult,
     ServingEngine,
 )
-from repro.serve.hedging import HedgedRunner, HedgePolicy  # noqa: F401
 from repro.serve.profile import StageProfiler  # noqa: F401
 from repro.serve.plan import (  # noqa: F401
     PRESETS,

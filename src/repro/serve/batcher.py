@@ -162,10 +162,10 @@ class CoalescingBatcher:
                  retry_jitter: float = 0.5,
                  retry_seed: int = 0):
         if getattr(engine, "_multiproc", False):
-            # same hazard class as hedging under SPMD: each process's
-            # batcher thread would form groups from its own wall-clock
-            # linger/scheduling, so dispatch sequences (and collective
-            # schedules) diverge across workers and the fleet deadlocks.
+            # SPMD lockstep hazard: each process's batcher thread would
+            # form groups from its own wall-clock linger/scheduling, so
+            # dispatch sequences (and collective schedules) diverge
+            # across workers and the fleet deadlocks.
             # Multi-process serving drives score_coalesced directly in
             # lockstep (repro.dist.runner).
             raise ValueError(

@@ -4,8 +4,7 @@
 two-stage pipeline of ``repro.core.split`` and scores candidate pools
 against cached user representations. This module is the *orchestration*
 layer of the serve subsystem — queueing/coalescing lives in
-``repro.serve.batcher``, the bounded rep store in ``repro.serve.cache``,
-and straggler hedging in ``repro.serve.hedging``.
+``repro.serve.batcher`` and the bounded rep store in ``repro.serve.cache``.
 
 Execution model — ONE row-wise stage-2 executable for everything:
 
@@ -36,7 +35,7 @@ JSON-serializable config spine shared by every entry point::
 ``plan.graph`` picks the paradigm and MaRI-rewrite shape, ``plan.kernel``
 the Pallas dispatch (fused ``mari_dense``, rep-table ``kernel_gather`` at
 accumulator-init load, gather-at-load ``gather_attention`` boundaries),
-``plan.batch`` the bucketing/coalescing/hedging envelope, ``plan.shard``
+``plan.batch`` the bucketing/coalescing envelope, ``plan.shard``
 candidate-axis sharding on the ``repro.dist`` 'cand' mesh (single-process
 ``jax.sharding`` or SPMD across ``jax.distributed`` workers, optional int8
 score gather), and ``plan.cache`` the bounded LRU user-rep store. Invalid
@@ -49,10 +48,9 @@ use_pallas=..., ...)`` — still works as a thin shim that builds the
 equivalent plan and emits a ``DeprecationWarning``; scores are identical
 to the plan path by construction (proven by test).
 
-Two runtime-dependent adjustments stay here rather than in the plan: a
-multi-process 'cand' mesh forces ``hedging`` off (per-process duplicates
-would desynchronize the SPMD collective schedule), and a sharded engine
-rounds ``max_batch`` down to a shard-divisible power of two.
+One runtime-dependent adjustment stays here rather than in the plan: a
+sharded engine rounds ``max_batch`` down to a shard-divisible power of
+two.
 
 Hot-path dispatch (``plan.cache.device_resident``) — the allocation-free
 stage-2 pipeline:
@@ -70,9 +68,7 @@ stage-2 pipeline:
   write), transferred, and donated to the stage-2 executable
   (``donate_argnums``), so steady-state serving performs zero fresh
   device allocations. Donated arguments are consumed: callers must never
-  retain them, which is why ``device_resident`` forces ``hedging`` off
-  (a hedged duplicate would replay deleted buffers — resolved at plan
-  construction).
+  retain them.
 * **async unpack** — launches are non-blocking and the call is a
   pipeline: after the table-write barrier, each pack is prepared and
   launched in turn, so the host packs bucket k+1 while the device
@@ -135,7 +131,6 @@ from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import DEFAULT_CAPACITY, Tracer, span
 from repro.serve.cache import EVICT, DeviceRepStore, UserRepCache
 from repro.serve.errors import FaultInjected
-from repro.serve.hedging import HedgedRunner, HedgePolicy
 from repro.serve.plan import ServePlan
 from repro.serve.profile import StageProfiler
 
@@ -153,9 +148,6 @@ from repro.serve.profile import StageProfiler
 # 16.4 MB a set) that is 197 MB.
 _PACK_SETS_PER_BUCKET = 6
 
-# HedgedRunner counters surfaced by ``hedge_counters`` and the registry
-_HEDGE_COUNTERS = ("hedges_launched", "hedge_wins", "pool_exhausted")
-
 
 @dataclasses.dataclass
 class ServeRequest:
@@ -171,7 +163,6 @@ class ServeResult:
     latency_ms: float            # wall time of the (possibly shared) batch
     n_batches: int               # stage-2 dispatches this request took part in
     user_cache_hit: bool
-    hedged: int = 0              # dispatches that launched a duplicate
     stage1_ms: float = 0.0       # 0 when cached / single-stage
     coalesced: bool = False      # scored inside a cross-user batch
     degraded: bool = False       # candidate pool truncated under overload
@@ -225,7 +216,7 @@ class _InFlight:
     reqs: Sequence[ServeRequest]
     infos: list
     packs: list
-    launched: list                # per pack: (outs, hedged, blocked)
+    launched: list                # per pack: its stage-2 outputs
     t0: float
     gid: int = 0                  # engine-wide group id (trace context)
     track: str | None = None      # synthetic trace track while outstanding
@@ -241,7 +232,6 @@ class _InFlight:
 class ServingEngine:
     def __init__(self, graph: Graph, params: dict,
                  plan: ServePlan | str | None = None, *,
-                 hedge_policy: HedgePolicy | None = None,
                  cache: UserRepCache | None = None,
                  cache_scope: Hashable | None = None,
                  **legacy_kwargs):
@@ -251,8 +241,7 @@ class ServingEngine:
         ``cache_scope`` let a host (``RankingService``) inject a SHARED
         ``UserRepCache``: cache keys are namespaced by ``cache_scope`` so
         several scenario engines can split one LRU budget without key
-        collisions. ``hedge_policy`` stays a constructor argument (it is a
-        live object, not serializable plan material).
+        collisions.
 
         Passing the old keyword knobs instead of ``plan`` still works: the
         legacy shim builds the equivalent plan (fail-fast validation
@@ -283,7 +272,6 @@ class ServingEngine:
         gather_attention = plan.kernel.gather_attention
         precat_weights = plan.kernel.precat_weights
         max_batch = plan.batch.max_batch
-        hedging = plan.batch.hedging
         shard_candidates = plan.shard.shard_candidates
         compress_scores = plan.shard.compress_scores
 
@@ -352,11 +340,6 @@ class ServingEngine:
             self._n_shards = int(self.mesh.devices.size)
             self._multiproc = len({d.process_index
                                    for d in self.mesh.devices.flat}) > 1
-            if self._multiproc:
-                # SPMD lockstep: every process must issue the identical
-                # dispatch sequence, so a per-process duplicate execution
-                # (hedging) would desynchronize the collective schedule.
-                hedging = False
             # buckets stay multiples of the shard count (pow2 / pow2):
             # no shard ever receives a ragged tail. The row cap itself must
             # divide evenly over the mesh, so a non-pow2 max_batch rounds
@@ -460,13 +443,6 @@ class ServingEngine:
             # frees the user's slot for the next resident user
             self.cache.subscribe(self._device_store.drop)
         self._donate_stage2 = self.device_resident
-        if self._donate_stage2:
-            # plan construction already resolves device_resident+hedging
-            # to hedging=False; enforce it here too (mirroring the
-            # multi-process override) so a plan that slipped past
-            # resolution can never hand HedgedRunner donated uidx/cand
-            # buffers — a hedged duplicate would replay consumed arrays
-            hedging = False
 
         self._stage2 = self._build_rowwise(batched_graph, exec_mode,
                                            use_pallas)
@@ -490,10 +466,6 @@ class ServingEngine:
 
         self.stage1_calls = 0                 # trace counter for the split test
         self.stage2_calls = 0                 # total row-wise dispatches
-        self.stage2_blocking_launches = 0     # stage-2 launches that waited
-        #                                       for their result (a hedging
-        #                                       engine's: hedged runs and
-        #                                       compile calls)
         self.coalesced_calls = 0              # dispatches mixing >1 user slot
         self.pipeline_forks = 0               # copy-on-write table forks
         #                                       (begin_coalesced needed a row
@@ -543,8 +515,6 @@ class ServingEngine:
                     ("cache_evictions", lambda: self.cache.evictions),
                     ("stage1_calls", lambda: self.stage1_calls),
                     ("stage2_calls", lambda: self.stage2_calls),
-                    ("stage2_blocking_launches",
-                     lambda: self.stage2_blocking_launches),
                     ("rep_stack_bytes", lambda: self.rep_stack_bytes),
                     ("pack_buffers_allocated",
                      lambda: self.pack_buffers_allocated),
@@ -559,14 +529,6 @@ class ServingEngine:
         self._group_ids = itertools.count(1)  # group ids (new_group_id)
         self._group_slots: set[int] = set()  # outstanding trace tracks
         self._trace_req_seq = 0       # engine-side request sampling seq
-        self.hedge_policy = hedge_policy or HedgePolicy()
-        self.hedging = hedging
-        self._hedged = (HedgedRunner(self._dispatch, self.hedge_policy)
-                        if hedging else None)
-        if self.metrics is not None and self._hedged is not None:
-            for name in _HEDGE_COUNTERS:
-                self.metrics.gauge(
-                    name, lambda name=name: getattr(self._hedged, name))
 
         # -- fault tolerance (plan.ft): deterministic injection + the
         # stage-2 circuit breaker + device-tier quarantine. Off by default:
@@ -1082,11 +1044,11 @@ class ServingEngine:
             # some later, unrelated write
             self._device_store.clear_fork_mark()
 
-        # pipelined prepare+launch: launches are non-blocking (unless
-        # hedging owns the dispatch), so the buffer fill + transfer of
-        # pack k+1 overlaps the device compute of pack k. Each pack holds
-        # its staging set until collect (_prepare_pack) — pack k's
-        # host->device copy may still be pending on the device stream here.
+        # pipelined prepare+launch: launches are non-blocking, so the
+        # buffer fill + transfer of pack k+1 overlaps the device compute
+        # of pack k. Each pack holds its staging set until collect
+        # (_prepare_pack) — pack k's host->device copy may still be
+        # pending on the device stream here.
         launched = []
         bufsets = []
         try:
@@ -1105,9 +1067,8 @@ class ServingEngine:
             # never leave untracked launches behind: a later call's table
             # write could otherwise run under them. The call's staging
             # sets are dropped, not recycled.
-            for out, _, blocked in launched:
-                if not blocked:
-                    jax.block_until_ready(out)
+            for out in launched:
+                jax.block_until_ready(out)
             raise
 
         handle = _InFlight(reqs=reqs, infos=infos, packs=packs,
@@ -1123,21 +1084,18 @@ class ServingEngine:
         Handles stay collectible — their results are simply already
         materialized when ``collect`` runs."""
         for h in self._inflight:
-            for out, _, blocked in h.launched:
-                if not blocked:
-                    jax.block_until_ready(out)
+            for out in h.launched:
+                jax.block_until_ready(out)
 
     def poll(self, handle: _InFlight) -> bool:
         """Non-blocking readiness probe: True when ``collect(handle)``
-        would not wait on the device (every non-blocked launch's outputs
-        are ready). Conservatively False on backends whose arrays expose
-        no readiness — callers fall back to collecting at the blocking
+        would not wait on the device (every launch's outputs are ready).
+        Conservatively False on backends whose arrays expose no
+        readiness — callers fall back to collecting at the blocking
         points. This is what lets the continuous loop harvest a finished
         group the moment it completes instead of holding its results
         through the next group's linger window."""
-        for out, _, blocked in handle.launched:
-            if blocked:
-                continue
+        for out in handle.launched:
             for leaf in jax.tree_util.tree_leaves(out):
                 ready = getattr(leaf, "is_ready", None)
                 if ready is None or not ready():
@@ -1165,9 +1123,8 @@ class ServingEngine:
             # must not leave untracked launches behind, and the group
             # trace span must close so traces stay B/E-balanced. The
             # group's staging sets are dropped, not recycled.
-            for out, _, blocked in handle.launched:
-                if not blocked:
-                    jax.block_until_ready(out)
+            for out in handle.launched:
+                jax.block_until_ready(out)
             if trc is not None and handle.track is not None:
                 trc.end("group", track=handle.track, group=handle.gid,
                         error=True)
@@ -1185,14 +1142,12 @@ class ServingEngine:
         # collect sweep: block on device, materialize, slice per request
         per_req_scores: list[list[np.ndarray]] = [[] for _ in reqs]
         per_req_packs = [0] * len(reqs)
-        per_req_hedged = [0] * len(reqs)
-        for (pack_items, _, _), (out, hedged, blocked), on_slots in zip(
+        for (pack_items, _, _), out, on_slots in zip(
                 packs, launched, slots_mask):
             total = sum(n for _, _, _, n in pack_items)
-            if not blocked:
-                with span("device", phase="device", tracer=trc,
-                          profiler=prof, group=handle.gid):
-                    jax.block_until_ready(out)
+            with span("device", phase="device", tracer=trc,
+                      profiler=prof, group=handle.gid):
+                jax.block_until_ready(out)
             act = self._poke("collect", group=handle.gid)
             with span("unpack", phase="unpack", tracer=trc, profiler=prof,
                       group=handle.gid):
@@ -1227,7 +1182,6 @@ class ServingEngine:
                 touched.add(ri)
             for ri in touched:
                 per_req_packs[ri] += 1
-                per_req_hedged[ri] += hedged
 
         # every pack's outputs are ready, so its host->device copies have
         # run: the staging sets may be refilled by later packs
@@ -1245,7 +1199,7 @@ class ServingEngine:
         return [ServeResult(
             scores=np.concatenate(per_req_scores[ri], axis=0),
             latency_ms=wall_ms, n_batches=per_req_packs[ri],
-            user_cache_hit=infos[ri].hit, hedged=per_req_hedged[ri],
+            user_cache_hit=infos[ri].hit,
             stage1_ms=infos[ri].stage1_ms, coalesced=len(reqs) > 1,
             cold_hit=infos[ri].cold_hit)
             for ri in range(len(reqs))]
@@ -1434,8 +1388,9 @@ class ServingEngine:
                 cand = {k: jnp.array(v) for k, v in cand_bufs.items()}
                 uidx_arr = jnp.array(uidx_buf)
 
-        # first call at a new (rep-table, bucket) signature compiles — that
-        # is not a straggler, so hedging would only duplicate the compile
+        # first call at a new (rep-table, bucket) signature compiles: its
+        # abstract arguments feed stage2_executables, and the dispatch
+        # span marks the call
         first_shape = (u_dim, bucket) not in self._batch_shapes
         if first_shape:
             self._batch_shapes[(u_dim, bucket)] = jax.tree.map(
@@ -1460,80 +1415,34 @@ class ServingEngine:
                 False)
 
     # -- dispatch ------------------------------------------------------------
-    def _launch_pack(self, prep, *, on_slots: bool, gid: int
-                     ) -> tuple[dict, int, bool]:
-        """Launch one prepared pack. Returns (outputs, hedged count,
-        blocked) — ``blocked`` marks results already materialized: a
-        hedging engine waits for every launch (the hedge owns its own
-        latency observation and must see final latencies), and
-        ``stage2_blocking_launches`` counts those waits. Without hedging
-        the launch only enqueues, and ``collect`` waits. ``on_slots``
+    def _launch_pack(self, prep, *, on_slots: bool, gid: int) -> dict:
+        """Launch one prepared pack and return its outputs. The launch
+        only enqueues stage 2 (and the optional compressed gather):
+        results stay on the device until ``collect`` waits for them and
+        materializes them. ``on_slots``
         marks the device-resident fast path: a failed launch there counts
         toward the circuit breaker."""
         table, uidx_arr, cand, n_slots, first_shape = prep
         self.stage2_calls += 1
         if n_slots > 1:
             self.coalesced_calls += 1
-        prof = self.profiler
-        trc = self.tracer
         try:
             self._poke("stage2_dispatch")
+            with span("dispatch", phase="dispatch", tracer=self.tracer,
+                      profiler=self.profiler, group=gid,
+                      bucket=int(uidx_arr.shape[0]),
+                      first_shape=first_shape):
+                out = self._stage2(self._params_s2, table, uidx_arr, cand)
+                if self._cgather is not None:
+                    # opt-in int8 result collection: the only cross-shard
+                    # movement of the step runs quantized
+                    # (repro.dist.compress)
+                    out = {k: self._cgather(v) for k, v in out.items()}
+                return out
         except Exception:
             if on_slots and self.breaker is not None:
                 self.breaker.record_failure()
             raise
-        hedge = self._hedged is not None and not first_shape
-        with span("dispatch", phase="dispatch", tracer=trc, profiler=prof,
-                  group=gid, bucket=int(uidx_arr.shape[0]),
-                  first_shape=first_shape):
-            if hedge:
-                out, outcome = self._hedged.run(
-                    self._params_s2, table, uidx_arr, cand, gid)
-            else:
-                try:
-                    out = self._execute(self._params_s2, table, uidx_arr,
-                                        cand)
-                except Exception:
-                    if on_slots and self.breaker is not None:
-                        self.breaker.record_failure()
-                    raise
-        if self._hedged is None:
-            return out, 0, False
-        self.stage2_blocking_launches += 1
-        if hedge:
-            return out, int(outcome.hedged), True
-        # compile call of a hedging engine: block here (latency would
-        # poison the policy window, so it is not observed either)
-        with span("device", phase="device", tracer=trc, profiler=prof,
-                  group=gid):
-            jax.block_until_ready(out)
-        return out, 0, True
-
-    def _execute(self, params, table, uidx, cand):
-        """Enqueue stage 2 (+ optional compressed gather) WITHOUT blocking:
-        results stay on device until the collect sweep materializes them."""
-        out = self._stage2(params, table, uidx, cand)
-        if self._cgather is not None:
-            # opt-in int8 result collection: the only cross-shard movement
-            # of the step runs quantized (repro.dist.compress)
-            out = {k: self._cgather(v) for k, v in out.items()}
-        return out
-
-    def _dispatch(self, params, table, uidx, cand, gid):
-        """One hedged stage-2 execution, run to completion (on the hedge
-        pool's thread, or inline when the pool is exhausted)."""
-        with span("stage2", tracer=self.tracer, group=gid):
-            out = self._execute(params, table, uidx, cand)
-            jax.block_until_ready(out)
-        return out
-
-    def hedge_counters(self) -> dict[str, int | None]:
-        """The hedge runner's duplicates launched, duplicates that won,
-        and calls denied a pool worker; None each where the engine does
-        not hedge."""
-        return {name: (None if self._hedged is None
-                       else getattr(self._hedged, name))
-                for name in _HEDGE_COUNTERS}
 
     def invalidate_user(self, user_id: int) -> None:
         self.cache.invalidate_user(self._scoped_uid(user_id))
@@ -1548,5 +1457,3 @@ class ServingEngine:
         self._inflight.clear()
         if self._promoter is not None:
             self._promoter.stop()
-        if self._hedged is not None:
-            self._hedged.close()

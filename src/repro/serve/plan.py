@@ -15,9 +15,9 @@ Sections (each its own frozen dataclass):
   ``group_by_domain``, ``two_stage``;
 * ``KernelPlan`` — Pallas dispatch: ``use_pallas``, ``kernel_gather``,
   ``gather_attention``, ``precat_weights``;
-* ``BatchPlan``  — bucketing/coalescing/SLO/hedging plus the continuous
+* ``BatchPlan``  — bucketing/coalescing/SLO plus the continuous
   dispatch loop and admission control: ``max_batch``, ``min_bucket``,
-  ``max_users_per_batch``, ``hedging``, ``linger_ms``, ``max_coalesce``,
+  ``max_users_per_batch``, ``linger_ms``, ``max_coalesce``,
   ``deadline_linger_frac``, ``continuous``, ``max_inflight``,
   ``admission``, ``shed_queue_depth``, ``degrade_queue_depth``,
   ``degrade_frac``, ``deadline_headroom_ms``;
@@ -87,12 +87,6 @@ admission thresholds (``shed_queue_depth`` /          drop them + warn (the
                                                       reps; with no cache
                                                       there is nothing to
                                                       keep resident)
-``device_resident`` with ``hedging``                  drop ``hedging`` +
-                                                      warn — hedged
-                                                      duplicates replay
-                                                      arguments the donated
-                                                      stage-2 buffers have
-                                                      already consumed
 ``device_slots`` without ``device_resident``          drop ``device_slots``
                                                       + warn (it sizes the
                                                       device tier only)
@@ -162,11 +156,10 @@ benchmarks and recipes use. ``plan.evolve(graph__mode="uoi", ...)``
 derives a new plan with section fields replaced (double-underscore
 addresses ``<section>__<field>``).
 
-Runtime-dependent interactions stay in the engine: a multi-process 'cand'
-mesh forces ``hedging`` off (per-process duplicate execution would
-desynchronize the SPMD collective schedule), and a sharded engine rounds
-``max_batch`` down to a shard-divisible power of two — both depend on the
-device world at construction time, which a serialized plan cannot know.
+One runtime-dependent interaction stays in the engine: a sharded engine
+rounds ``max_batch`` down to a shard-divisible power of two — it depends
+on the device world at construction time, which a serialized plan cannot
+know.
 """
 from __future__ import annotations
 
@@ -209,12 +202,11 @@ class KernelPlan:
 
 @dataclasses.dataclass(frozen=True)
 class BatchPlan:
-    """Bucketing, cross-user coalescing, SLO linger, hedging, the
-    continuous dispatch loop, and SLO-tiered admission control."""
+    """Bucketing, cross-user coalescing, SLO linger, the continuous
+    dispatch loop, and SLO-tiered admission control."""
     max_batch: int = 4096              # stage-2 row budget per dispatch
     min_bucket: int = 128              # smallest pow2 candidate bucket
     max_users_per_batch: int = 8       # rep-table slot budget per pack
-    hedging: bool = True               # duplicate straggling dispatches
     linger_ms: float = 2.0             # batcher window for co-arrivals
     max_coalesce: int = 64             # request budget per batcher group
     deadline_linger_frac: float = 0.25  # linger shrink for deadline SLO
@@ -303,7 +295,6 @@ _LEGACY_KWARGS: dict[str, tuple[str, str]] = {
     "max_batch": ("batch", "max_batch"),
     "min_bucket": ("batch", "min_bucket"),
     "max_users_per_batch": ("batch", "max_users_per_batch"),
-    "hedging": ("batch", "hedging"),
     "shard_candidates": ("shard", "shard_candidates"),
     "compress_scores": ("shard", "compress_scores"),
     "cache_user_reps": ("cache", "cache_user_reps"),
@@ -327,8 +318,8 @@ _FIELD_TYPES: dict[str, dict[str, str]] = {
     "kernel": {"use_pallas": "bool", "kernel_gather": "bool",
                "gather_attention": "bool", "precat_weights": "bool"},
     "batch": {"max_batch": "int", "min_bucket": "int",
-              "max_users_per_batch": "int", "hedging": "bool",
-              "linger_ms": "num", "max_coalesce": "int",
+              "max_users_per_batch": "int", "linger_ms": "num",
+              "max_coalesce": "int",
               "deadline_linger_frac": "num", "continuous": "bool",
               "max_inflight": "int", "admission": "bool",
               "shed_queue_depth": "int?", "degrade_queue_depth": "int?",
@@ -561,7 +552,6 @@ class ServePlan:
                 dataclasses.replace(self.batch, shed_queue_depth=None,
                                     degrade_queue_depth=None,
                                     deadline_headroom_ms=0.0))
-            b = self.batch
         if c.device_resident and not c.cache_user_reps:
             notes.append(
                 "device_resident without cache_user_reps: the device tier "
@@ -572,15 +562,6 @@ class ServePlan:
                                dataclasses.replace(self.cache,
                                                    device_resident=False))
             c = self.cache
-        if c.device_resident and b.hedging:
-            notes.append(
-                "device_resident with hedging: hedged duplicates replay "
-                "arguments that the donated stage-2 buffers have already "
-                "consumed — resolved to hedging=False")
-            object.__setattr__(self, "batch",
-                               dataclasses.replace(self.batch,
-                                                   hedging=False))
-            b = self.batch
         if c.device_slots is not None and not c.device_resident:
             notes.append(
                 "device_slots without device_resident: it sizes the device "
@@ -810,17 +791,10 @@ PRESETS: dict[str, ServePlan] = {
     "vanilla": ServePlan(graph=GraphPlan(mode="vani")),
     "uoi": ServePlan(graph=GraphPlan(mode="uoi")),
     # everything the Pallas path offers: fused mari_dense with the
-    # kernel-side rep-table gather + gather-at-load decomposed attention.
-    # Hedging off: on one chip a duplicate queues on the same device
-    # stream behind its primary and cannot finish first, while the hedged
-    # launch blocks the worker until the pack is done; unhedged, a launch
-    # returns at once and the host packs the next pack meanwhile
+    # kernel-side rep-table gather + gather-at-load decomposed attention
     "tpu": ServePlan(graph=GraphPlan(mode="mari", reparam_attention=True),
                      kernel=KernelPlan(use_pallas=True, kernel_gather=True,
-                                       gather_attention=True),
-                     batch=BatchPlan(hedging=False)),
-    # candidate-axis sharding on the 'cand' mesh; hedging off because the
-    # multi-process SPMD schedule cannot tolerate per-process duplicates
-    "distributed": ServePlan(shard=ShardPlan(shard_candidates=True),
-                             batch=BatchPlan(hedging=False)),
+                                       gather_attention=True)),
+    # candidate-axis sharding on the 'cand' mesh
+    "distributed": ServePlan(shard=ShardPlan(shard_candidates=True)),
 }

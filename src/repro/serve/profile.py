@@ -8,10 +8,9 @@ two-stage ranker:
 * ``stage1``   — user-tower compute on cache miss (device, blocking);
 * ``pack``     — host-side bucket assembly: transfer-buffer fills, slot
   resolution, device-table row writes;
-* ``dispatch`` — enqueueing stage-2 executables (host time only when the
-  async-unpack path is active; includes device time on the blocking
-  hedged path);
-* ``device``   — waiting on stage-2 results (``block_until_ready``);
+* ``dispatch`` — enqueueing stage-2 executables (host time only);
+* ``device``   — waiting on stage-2 results (``block_until_ready``) at
+  collect;
 * ``unpack``   — materializing scores to host and slicing per-request
   views out of the bucket;
 * ``queue_idle`` — batcher-worker time blocked on an empty request queue

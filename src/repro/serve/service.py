@@ -237,12 +237,6 @@ class RankingService:
                                is not None else None),
                     "stage1_calls": s.engine.stage1_calls,
                     "stage2_calls": s.engine.stage2_calls,
-                    # stage-2 launches that waited for their result (only
-                    # a hedging engine's), and its hedge runner's
-                    # counters: None where the engine does not hedge
-                    "stage2_blocking_launches":
-                        s.engine.stage2_blocking_launches,
-                    **s.engine.hedge_counters(),
                     # candidate + user-index bytes handed to the device,
                     # padding rows included
                     "h2d_bytes": s.engine.h2d_bytes,

@@ -113,14 +113,12 @@ def test_gather_einsum_path_agrees_with_vani_on_three_coalesced_users():
     reqs = [request(u, n) for u, n in ((0, 7), (1, 19), (2, 12))]
     # the tpu preset's path: MaRI with the decomposed unit, its per-user
     # tables stacked and indexed inside the Pallas contractions
-    eng = ServingEngine(graph, params, plan=ServePlan.preset("tpu").evolve(
-        batch__hedging=False))
+    eng = ServingEngine(graph, params, plan=ServePlan.preset("tpu"))
     assert {"din_attn::T", "din_attn::u_part",
             "user_seq_emb"} <= eng.lazy_gather_inputs
     got = eng.score_coalesced(reqs)
     assert eng.coalesced_calls == 1 and eng.rep_stack_bytes > 0
-    vani = ServingEngine(graph, params, plan=ServePlan.preset(
-        "vanilla").evolve(batch__hedging=False))
+    vani = ServingEngine(graph, params, plan=ServePlan.preset("vanilla"))
     ref = ReferenceScorer(graph, params)
     atol, rtol = SCORE_TOL["cpu"]
     for g, v, r in zip(got, vani.score_coalesced(reqs), reqs):

@@ -258,10 +258,10 @@ class TestKernelGather:
 
         reqs = [req(0, 21, 1), req(1, 40, 2)]
         ref = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            min_bucket=16, use_pallas=True, hedging=False)
+                            min_bucket=16, use_pallas=True)
         lazy = ServingEngine(graph, params, mode="mari", max_batch=64,
                              min_bucket=16, use_pallas=True,
-                             kernel_gather=True, hedging=False)
+                             kernel_gather=True)
         # the paper model must actually exercise the lazy path — an empty
         # eligibility set would degrade this into ref-vs-ref
         assert lazy.kernel_gather and len(lazy.lazy_gather_inputs) > 0
@@ -351,11 +351,10 @@ class TestEngineShardingConfig:
             0, {k: v for k, v in feeds.items() if k in user_in},
             {k: v for k, v in feeds.items() if k not in user_in})
         ref = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            min_bucket=16, shard_candidates=True,
-                            hedging=False)
+                            min_bucket=16, shard_candidates=True)
         cmp_eng = ServingEngine(graph, params, mode="mari", max_batch=64,
                                 min_bucket=16, shard_candidates=True,
-                                compress_scores=True, hedging=False)
+                                compress_scores=True)
         assert cmp_eng._cgather is not None
         a = ref.score(req).scores
         b = cmp_eng.score(req).scores
@@ -386,7 +385,7 @@ class TestEngineShardingConfig:
         params = init_graph_params(graph, jax.random.PRNGKey(0))
         # shard count pinned to 1 so the assertion holds on any machine
         eng = ServingEngine(graph, params, max_batch=100, min_bucket=8,
-                            shard_candidates=1, hedging=False)
+                            shard_candidates=1)
         assert eng._n_shards == 1 and eng.max_batch == 100
         assert eng._bucket(100) == 100          # raw cap, seed behavior
 

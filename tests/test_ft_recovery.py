@@ -27,6 +27,7 @@ import pytest
 from repro.data.features import make_recsys_feeds
 from repro.ft import (CORRUPT, FaultInjector, FaultSpec, HeartbeatMonitor,
                       parse_fault_spec, plan_elastic_remesh)
+from repro.ft.failures import HedgePolicy
 from repro.ft.recovery import CLOSED, HALF_OPEN, OPEN, CircuitBreaker, \
     RetryPolicy
 from repro.graph.executor import init_graph_params
@@ -36,7 +37,6 @@ from repro.serve import (AdmissionError, BatcherClosedError,
                          PlanError, PlanResolutionWarning, RetryExhausted,
                          ServePlan, ServeRequest, ServeResult, ServingEngine,
                          WorkerCrashedError)
-from repro.serve.hedging import HedgedRunner, HedgePolicy
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +58,8 @@ def _request(graph, user_in, uid, n, seed, version=0):
 
 
 def _plan(**over):
-    base = dict(batch__max_batch=128, batch__hedging=False,
-                cache__device_resident=True, cache__device_slots=8)
+    base = dict(batch__max_batch=128, cache__device_resident=True,
+                cache__device_slots=8)
     base.update(over)
     return ServePlan().evolve(**base)
 
@@ -494,7 +494,7 @@ class TestEngineSelfHealing:
 
 
 # ---------------------------------------------------------------------------
-# Satellites: heartbeat resurrection, remesh edges, hedging pool
+# Satellites: heartbeat resurrection, remesh edges, hedge policy
 # ---------------------------------------------------------------------------
 
 class TestHeartbeatSticky:
@@ -563,31 +563,6 @@ class TestHedgingPool:
         for th in ts:
             th.join()
         assert not errs
-
-    def test_pool_exhaustion_runs_inline(self):
-        r = HedgedRunner(lambda x: x * 2, max_workers=1)
-        with r._olock:
-            r._outstanding = 1             # simulate a zombie-held worker
-        out, outcome = r.run(21)
-        assert out == 42 and not outcome.hedged
-        assert r.pool_exhausted == 1
-        with r._olock:
-            r._outstanding = 0
-        out, _ = r.run(5)                  # slot free again: normal path
-        assert out == 10 and r.pool_exhausted == 1
-        r.close()
-
-    def test_no_duplicate_when_pool_full_awaits_primary(self):
-        pol = HedgePolicy(min_hedge_ms=0.1)   # deadline 1 ms pre-window
-        r = HedgedRunner(lambda: (time.sleep(0.05), 7)[1],
-                         policy=pol, max_workers=1)
-        out, outcome = r.run()
-        # the primary held the only worker past the hedge deadline; the
-        # duplicate could not get a slot, so the runner awaited the
-        # primary instead of queueing a pointless copy behind it
-        assert out == 7 and not outcome.hedged
-        assert r.hedges_launched == 0 and r.pool_exhausted == 1
-        r.close()
 
 
 class TestErrorTaxonomy:

@@ -46,7 +46,7 @@ def _request(graph, user_in, uid, n=8, seed=None, version=0):
 def _cold_plan(**overrides):
     base = dict(cache__max_cached_users=2, mem__cold_tier=True,
                 mem__cold_bytes=1 << 22, mem__promote_touches=2,
-                batch__hedging=False, batch__linger_ms=0.0)
+                batch__linger_ms=0.0)
     base.update(overrides)
     return ServePlan().evolve(**base)
 
@@ -239,7 +239,7 @@ class TestEngineMemTier:
         eng = ServingEngine(graph, params, plan=plan)
         off = ServingEngine(graph, params, plan=ServePlan().evolve(
             graph__mode=mode, cache__cache_user_reps=False,
-            batch__hedging=False, batch__linger_ms=0.0))
+            batch__linger_ms=0.0))
         try:
             if mode == "vani":
                 # single-stage: no stage-1 outputs to tier — the cold
@@ -297,8 +297,7 @@ class TestEngineMemTier:
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, plan=_cold_plan())
         off = ServingEngine(graph, params, plan=ServePlan().evolve(
-            cache__cache_user_reps=False, batch__hedging=False,
-            batch__linger_ms=0.0))
+            cache__cache_user_reps=False, batch__linger_ms=0.0))
         try:
             reqs = [_request(graph, user_in, u) for u in range(5, 9)]
             n = eng.warm([(r.user_id, r.user_feeds) for r in reqs])
@@ -366,8 +365,7 @@ class TestEngineMemTier:
 
     def test_warm_requires_cold_tier(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, plan=ServePlan().evolve(
-            batch__hedging=False))
+        eng = ServingEngine(graph, params, plan=ServePlan())
         try:
             with pytest.raises(RuntimeError, match="cold_tier"):
                 eng.warm([(0, _request(graph, user_in, 0).user_feeds)])

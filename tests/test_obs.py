@@ -43,7 +43,7 @@ def _request(graph, user_in, uid, n, seed, version=0):
         feature_version=version)
 
 
-TRACE_PLAN = ServePlan().evolve(obs__trace=True, batch__hedging=False)
+TRACE_PLAN = ServePlan().evolve(obs__trace=True)
 
 
 # ---------------------------------------------------------------------------
@@ -481,7 +481,7 @@ class TestEngineTracing:
         snapshots keep working."""
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, plan=ServePlan().evolve(
-            obs__metrics=False, batch__hedging=False))
+            obs__metrics=False))
         assert eng.metrics is None
         with CoalescingBatcher(eng, linger_ms=1.0) as b:
             b.submit(_request(graph, user_in, 0, 9, seed=0)).result()
@@ -495,8 +495,7 @@ class TestEngineTracing:
     def test_tracing_engine_scores_bit_identical(self, paper):
         graph, params, user_in = paper
         reqs = [_request(graph, user_in, i, 9 + i, seed=i) for i in range(3)]
-        plain = ServingEngine(graph, params, plan=ServePlan().evolve(
-            batch__hedging=False))
+        plain = ServingEngine(graph, params, plan=ServePlan())
         traced = ServingEngine(graph, params, plan=TRACE_PLAN)
         for r in reqs:
             a = plain.score(r)
@@ -538,7 +537,6 @@ class TestEngineTracing:
 WORKER_SPANS = {"wait", "linger", "begin_coalesced", "stage1", "stage1_wait",
                 "slots", "pack", "fill", "h2d", "dispatch", "device",
                 "collect", "unpack", "resolve_group"}
-HEDGE_SPANS = {"stage2"}
 # StageProfiler phase -> the spans whose durations it totals
 PHASE_SPANS = {"stage1": ("stage1",), "pack": ("slots", "pack"),
                "dispatch": ("dispatch",), "device": ("device",),
@@ -568,13 +566,13 @@ def _profiled_spans(logdir):
 
 class TestProfilerSpans:
     def test_serving_spans_reach_the_profiler_clock(self, paper, tmp_path):
-        """An engine (hedging on) behind a continuous batcher, traced with
-        the Python tracer off: every span lands on the host plane with a
-        group id, children nest in their parents, and each StageProfiler
-        phase totals its spans."""
+        """An engine behind a continuous batcher, traced with the Python
+        tracer off: every span lands on the host plane with a group id,
+        on the worker's line, children nest in their parents, and each
+        StageProfiler phase totals its spans."""
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, plan=ServePlan().evolve(
-            batch__hedging=True, batch__continuous=True))
+            batch__continuous=True))
         eng.score(_request(graph, user_in, 100, 9, seed=100))  # warm U=1
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -582,8 +580,8 @@ class TestProfilerSpans:
         try:
             eng.profiler.snapshot(reset=True)
             with CoalescingBatcher.from_plan(eng, eng.plan.batch) as b:
-                # a new bucket first (a blocking compile launch: the
-                # device span), then hedged launches one group at a time
+                # a new bucket first (a compile launch), then one group
+                # at a time
                 for i, n in enumerate((200, 9, 12, 9)):
                     b.submit(_request(graph, user_in, i, n, seed=i)
                              ).result(timeout=120)
@@ -594,12 +592,13 @@ class TestProfilerSpans:
             eng.close()
         spans = _profiled_spans(str(tmp_path))
         names = {s[3] for s in spans}
-        assert WORKER_SPANS | HEDGE_SPANS <= names
+        assert WORKER_SPANS <= names
         assert all(isinstance(s[4].get("group"), int) for s in spans)
-        # the worker's spans sit on one line, the hedged stage 2 elsewhere
+        # stage 2 launches and waits on the worker's own line
         worker = {s[0] for s in spans if s[3] in ("wait", "linger")}
         assert len(worker) == 1
-        assert {s[0] for s in spans if s[3] == "stage2"} - worker
+        assert {s[0] for s in spans
+                if s[3] in ("dispatch", "device")} == worker
 
         def inside(child, parent):
             kids = [s for s in spans if s[3] == child]
@@ -623,8 +622,7 @@ class TestProfilerSpans:
 
     def test_h2d_bytes_count_the_padded_buffers(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, plan=ServePlan().evolve(
-            batch__hedging=False))
+        eng = ServingEngine(graph, params, plan=ServePlan())
         want = 0
         for uid, n in ((0, 9), (1, 300)):
             req = _request(graph, user_in, uid, n, seed=uid)
@@ -636,7 +634,7 @@ class TestProfilerSpans:
             assert eng.h2d_bytes == want
         assert eng._bucket(9) > 9                # padding rows counted
         eng.close()
-        svc = RankingService(ServePlan().evolve(batch__hedging=False))
+        svc = RankingService(ServePlan())
         svc.register("ranking", graph=graph, params=params)
         svc.score("ranking", _request(graph, user_in, 0, 9, seed=0))
         st = svc.stats()["scenarios"]["ranking"]
@@ -681,7 +679,7 @@ class TestProfilerSpans:
     def test_executables_carry_stable_names(self, paper):
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, plan=ServePlan().evolve(
-            batch__hedging=False, cache__device_resident=True))
+            cache__device_resident=True))
         eng.score(_request(graph, user_in, 0, 9, seed=0))
         exes = eng.stage2_executables()
         assert exes and all("jit_serve_stage2" in c.as_text()

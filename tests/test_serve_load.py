@@ -46,8 +46,8 @@ def _request(graph, user_in, uid, n, seed, version=0):
 
 
 def _plan(**over):
-    base = dict(batch__max_batch=128, batch__hedging=False,
-                cache__device_resident=True, cache__device_slots=8)
+    base = dict(batch__max_batch=128, cache__device_resident=True,
+                cache__device_slots=8)
     base.update(over)
     return ServePlan().evolve(**base)
 
@@ -146,7 +146,6 @@ class TestContinuousLoopIdentity:
         candidates ride the same buffers)."""
         graph, params, user_in = paper
         plan = ServePlan().evolve(batch__max_batch=1024,
-                                  batch__hedging=False,
                                   cache__device_resident=False)
         eng = ServingEngine(graph, params, plan=plan)
         # big fills 6 full packs; victim lands alone in a 7th pack whose
@@ -224,7 +223,6 @@ class TestPackBufferPool:
     def test_overlapped_packs_never_share_a_staging_set(self, paper):
         graph, params, user_in = paper
         plan = ServePlan().evolve(batch__max_batch=1024,
-                                  batch__hedging=False,
                                   cache__device_resident=False)
         eng = ServingEngine(graph, params, plan=plan)
         big = _request(graph, user_in, 0, 3 * 1024, seed=0)
@@ -464,7 +462,7 @@ class TestAdmissionControl:
         assert b.shed_requests == 0
 
     def test_service_stats_surface_shed_counters(self):
-        plan = ServePlan().evolve(batch__hedging=False, batch__admission=True,
+        plan = ServePlan().evolve(batch__admission=True,
                                   batch__shed_queue_depth=64,
                                   batch__deadline_headroom_ms=1.0)
         with RankingService(plan, smoke=True, seed=0) as svc:

@@ -55,8 +55,7 @@ class TestServePlanBasics:
     def test_round_trip_of_nondefault_plan(self):
         plan = ServePlan(
             graph=GraphPlan(mode="uoi", two_stage=True),
-            batch=BatchPlan(max_batch=256, min_bucket=32, hedging=False,
-                            linger_ms=7.5),
+            batch=BatchPlan(max_batch=256, min_bucket=32, linger_ms=7.5),
             shard=ShardPlan(shard_candidates=2),
             cache=CachePlan(max_cached_users=100))
         rt = ServePlan.from_json(plan.to_json())
@@ -69,8 +68,6 @@ class TestServePlanBasics:
         assert ServePlan.preset("vanilla").graph.mode == "vani"
         assert ServePlan.preset("tpu").kernel.use_pallas
         assert ServePlan.preset("distributed").shard.shard_candidates
-        # distributed preset must be SPMD-safe out of the box
-        assert not ServePlan.preset("distributed").batch.hedging
         with pytest.raises(PlanError, match="unknown preset"):
             ServePlan.preset("bogus")
 
@@ -131,8 +128,8 @@ class TestServePlanBasics:
         assert ServePlan.load(str(p)) == plan
 
     def test_dist_runner_plan_file_fields_survive(self, tmp_path):
-        """The SPMD runner layers only its operating requirements (sharding
-        on, hedging off) on a --plan file — the file's max_batch/min_bucket/
+        """The SPMD runner layers only its operating requirement (sharding
+        on) on a --plan file — the file's max_batch/min_bucket/
         compress_scores must survive unless flags explicitly override."""
         import argparse
         from repro.dist.runner import build_plan
@@ -147,7 +144,7 @@ class TestServePlanBasics:
         assert plan.batch.max_batch == 1024
         assert plan.batch.min_bucket == 64
         assert plan.shard.compress_scores          # file value survives
-        assert plan.shard.shard_candidates and not plan.batch.hedging
+        assert plan.shard.shard_candidates
         # an explicit shard COUNT in the file survives the forced-on rule
         path2 = tmp_path / "plan2.json"
         ServePlan(shard=ShardPlan(shard_candidates=2)).save(str(path2))
@@ -307,8 +304,7 @@ class TestAdmissionPlanFields:
         """CoalescingBatcher.from_plan carries every batch-section knob."""
         from repro.serve import CoalescingBatcher
         graph, params, _ = din_problem
-        plan = ServePlan().evolve(batch__hedging=False,
-                                  batch__continuous=False,
+        plan = ServePlan().evolve(batch__continuous=False,
                                   batch__max_inflight=3,
                                   batch__admission=True,
                                   batch__shed_queue_depth=9,
@@ -341,14 +337,6 @@ class TestDeviceResidentPlan:
         assert not plan.cache.device_resident
         assert plan.resolution_notes
 
-    def test_device_resident_drops_hedging(self):
-        # BatchPlan defaults hedging=True; the device tier wins (hedged
-        # duplicates would replay donated dispatches)
-        with pytest.warns(PlanResolutionWarning, match="hedging"):
-            plan = ServePlan(cache=CachePlan(device_resident=True))
-        assert plan.cache.device_resident
-        assert not plan.batch.hedging
-
     def test_device_slots_without_device_resident_dropped(self):
         with pytest.warns(PlanResolutionWarning, match="device_slots"):
             plan = ServePlan(cache=CachePlan(device_slots=8))
@@ -357,8 +345,7 @@ class TestDeviceResidentPlan:
     def test_valid_combo_silent_and_roundtrips(self):
         with warnings.catch_warnings():
             warnings.simplefilter("error")
-            plan = ServePlan(batch=BatchPlan(hedging=False),
-                             cache=CachePlan(device_resident=True,
+            plan = ServePlan(cache=CachePlan(device_resident=True,
                                              device_slots=32))
             rt = ServePlan.from_json(plan.to_json())
         assert rt == plan
@@ -373,11 +360,9 @@ class TestLegacyShim:
     def test_legacy_kwargs_deprecation_warning(self, din_problem):
         graph, params, _ = din_problem
         with pytest.warns(DeprecationWarning, match="ServePlan"):
-            eng = ServingEngine(graph, params, mode="uoi", max_batch=32,
-                                hedging=False)
+            eng = ServingEngine(graph, params, mode="uoi", max_batch=32)
         assert eng.plan == ServePlan(graph=GraphPlan(mode="uoi"),
-                                     batch=BatchPlan(max_batch=32,
-                                                     hedging=False))
+                                     batch=BatchPlan(max_batch=32))
         eng.close()
 
     def test_plan_path_does_not_warn(self, din_problem):
@@ -411,8 +396,7 @@ class TestLegacyShim:
     def test_shim_kernel_gather_without_pallas_warns(self, din_problem):
         graph, params, _ = din_problem
         with pytest.warns(PlanResolutionWarning, match="kernel_gather"):
-            eng = ServingEngine(graph, params, kernel_gather=True,
-                                hedging=False)
+            eng = ServingEngine(graph, params, kernel_gather=True)
         assert not eng.kernel_gather
         eng.close()
 
@@ -420,7 +404,7 @@ class TestLegacyShim:
         graph, params, _ = din_problem
         with pytest.warns(PlanResolutionWarning, match="gather_attention"):
             eng = ServingEngine(graph, params, mode="vani",
-                                gather_attention=True, hedging=False)
+                                gather_attention=True)
         assert not eng.gather_attention
         eng.close()
 
@@ -438,16 +422,31 @@ class TestLegacyShim:
                 _request(graph, user_in, 1, 21, seed=2)]
         with pytest.warns(DeprecationWarning):
             legacy = ServingEngine(graph, params, mode=mode, max_batch=32,
-                                   min_bucket=8, hedging=False)
+                                   min_bucket=8)
         plan_eng = ServingEngine(graph, params, plan=ServePlan().evolve(
-            graph__mode=mode, batch__max_batch=32, batch__min_bucket=8,
-            batch__hedging=False))
+            graph__mode=mode, batch__max_batch=32, batch__min_bucket=8))
         assert legacy.plan == plan_eng.plan
         for a, b in zip(legacy.score_coalesced(reqs),
                         plan_eng.score_coalesced(reqs)):
             np.testing.assert_array_equal(a.scores, b.scores)
         legacy.close()
         plan_eng.close()
+
+
+@pytest.mark.parametrize("entry", ["from_json", "evolve", "legacy_kwargs"])
+def test_removed_hedging_knob_is_refused(request, entry):
+    """Stage-2 hedging is gone: a plan or engine naming it fails at every
+    entry point instead of being silently ignored."""
+    if entry == "from_json":
+        with pytest.raises(PlanError, match="hedging"):
+            ServePlan.from_json('{"batch": {"hedging": false}}')
+    elif entry == "evolve":
+        with pytest.raises(TypeError, match="hedging"):
+            ServePlan().evolve(batch__hedging=False)
+    else:
+        graph, params, _ = request.getfixturevalue("din_problem")
+        with pytest.raises(TypeError, match="hedging"):
+            ServingEngine(graph, params, hedging=False)
 
 
 class TestRankingService:
@@ -457,7 +456,6 @@ class TestRankingService:
     @pytest.fixture(scope="class")
     def svc_plan(self):
         return ServePlan().evolve(batch__max_batch=64, batch__min_bucket=16,
-                                  batch__hedging=False,
                                   batch__linger_ms=20.0,
                                   batch__max_coalesce=4)
 

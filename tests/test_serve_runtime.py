@@ -1,5 +1,5 @@
 """The async coalescing serve runtime: cross-user stage-2 batching, bounded
-LRU user-rep cache, real hedged execution, weight pre-concatenation, and
+LRU user-rep cache, weight pre-concatenation, and
 candidate-axis sharding. Scores from differently shaped executables are
 checked against the float32 reference within the stated CPU tolerance
 (``repro.serve.reference``); bit-equality is asserted only where XLA:CPU
@@ -20,8 +20,8 @@ from repro.data.features import make_recsys_feeds
 from repro.graph.executor import init_graph_params
 from repro.models.ranking import PaperRankingConfig, build_paper_ranking_model
 from repro.models.recsys import build_din
-from repro.serve import (CoalescingBatcher, HedgedRunner, HedgePolicy,
-                         ServePlan, ServeRequest, ServingEngine)
+from repro.serve import (CoalescingBatcher, ServePlan, ServeRequest,
+                         ServingEngine)
 from repro.serve.cache import DeviceRepStore, UserRepCache
 from repro.serve.reference import SCORE_TOL, ReferenceScorer
 
@@ -103,7 +103,7 @@ class TestUserRepCache:
     def test_engine_surfaces_evictions(self, paper):
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, mode="mari", max_batch=32,
-                            max_cached_users=2, hedging=False)
+                            max_cached_users=2)
         for uid in range(4):
             eng.score(_request(graph, user_in, uid, 9, seed=uid))
         assert len(eng.cache) == 2
@@ -124,8 +124,7 @@ class TestCoalescedLossless:
     @pytest.mark.parametrize("mode", ["vani", "uoi", "mari"])
     def test_modes_bit_identical(self, paper, mode):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode=mode, max_batch=128,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode=mode, max_batch=128)
         reqs = [_request(graph, user_in, 0, 23, seed=1),
                 _request(graph, user_in, 1, 40, seed=2),
                 _request(graph, user_in, 2, 7, seed=3),
@@ -149,14 +148,13 @@ class TestCoalescedLossless:
 
     def test_mixed_hits_and_misses_one_batch(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=256,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=256)
         warm = _request(graph, user_in, 7, 20, seed=7)
         ref_warm = eng.score(warm)                  # user 7 now cached
         fresh = [_request(graph, user_in, 8, 33, seed=8),
                  _request(graph, user_in, 9, 12, seed=9)]
-        ref_fresh = [ServingEngine(graph, params, mode="mari", max_batch=256,
-                                   hedging=False).score(r) for r in fresh]
+        ref_fresh = [ServingEngine(graph, params, mode="mari",
+                                   max_batch=256).score(r) for r in fresh]
         co = eng.score_coalesced([warm] + fresh)
         assert co[0].user_cache_hit and not co[1].user_cache_hit
         _assert_matches_reference(graph, params, [warm] + fresh,
@@ -166,7 +164,7 @@ class TestCoalescedLossless:
     def test_pool_larger_than_max_batch_spills_chunks(self, paper):
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            min_bucket=16, hedging=False)
+                            min_bucket=16)
         reqs = [_request(graph, user_in, 0, 150, seed=1),   # 64+64+22
                 _request(graph, user_in, 1, 30, seed=2)]    # tail shares
         per = [eng.score(r) for r in reqs]
@@ -183,8 +181,7 @@ class TestCoalescedLossless:
         user_in = {n.name for n in graph.input_nodes()
                    if n.attrs.get("domain") == "user"}
         eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            min_bucket=8, reparam_attention=True,
-                            hedging=False)
+                            min_bucket=8, reparam_attention=True)
         reqs = [_request(graph, user_in, u, n, seed=u + 1)
                 for u, n in ((0, 11), (1, 17), (2, 5))]
         per = [eng.score(r) for r in reqs]
@@ -208,7 +205,7 @@ class TestCoalescedLossless:
         graph = b.graph
         params = init_graph_params(graph, jax.random.PRNGKey(0))
         eng = ServingEngine(graph, params, mode="mari", max_batch=32,
-                            min_bucket=8, hedging=False)
+                            min_bucket=8)
         assert not eng.two_stage
         ks = jax.random.split(jax.random.PRNGKey(1), 12)
         reqs = []
@@ -224,8 +221,7 @@ class TestCoalescedLossless:
 
     def test_compiled_shape_family_bounded(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=128,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=128)
         for n in (10, 50, 100):
             eng.score(_request(graph, user_in, 0, n, seed=n))
         eng.score_coalesced([_request(graph, user_in, u, 20, seed=u)
@@ -253,7 +249,6 @@ class TestGatherAttention:
 
     def _engine(self, din_fixture, **kw):
         graph, params, _ = din_fixture
-        kw.setdefault("hedging", False)
         return ServingEngine(graph, params, mode="mari", max_batch=64,
                              min_bucket=8, reparam_attention=True, **kw)
 
@@ -286,7 +281,7 @@ class TestGatherAttention:
         graph, params, user_in = din
         eng = ServingEngine(graph, params, mode=mode, max_batch=64,
                             min_bucket=8, reparam_attention=True,
-                            gather_attention=True, hedging=False)
+                            gather_attention=True)
         if mode != "mari":
             assert not eng.lazy_gather_inputs
         reqs = [_request(graph, user_in, u, n, seed=u + 7)
@@ -340,8 +335,7 @@ class TestSingleStageCacheBypass:
 
     def test_vani_never_touches_cache(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="vani", max_batch=32,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="vani", max_batch=32)
         assert not eng.two_stage and not eng.cache_user_reps
         for uid in range(3):
             r = eng.score(_request(graph, user_in, uid, 9, seed=uid))
@@ -352,8 +346,7 @@ class TestSingleStageCacheBypass:
 
     def test_two_stage_still_caches(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=32,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=32)
         assert eng.cache_user_reps
         eng.score(_request(graph, user_in, 5, 9, seed=5))
         assert eng.score(
@@ -372,7 +365,7 @@ class TestPrecatWeights:
         kw = {layout: True}
         engines = [ServingEngine(graph, params, mode="mari", max_batch=64,
                                  precat_weights=p, use_pallas=use_pallas,
-                                 hedging=False, **kw) for p in (False, True)]
+                                 **kw) for p in (False, True)]
         reqs = [_request(graph, user_in, u, n, seed=u + 1)
                 for u, n in ((0, 21), (1, 40))]
         r_off = engines[0].score_coalesced(reqs)
@@ -382,7 +375,7 @@ class TestPrecatWeights:
     def test_w_cat_present_on_stage2_nodes(self, paper):
         graph, params, user_in = paper
         eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            group_by_domain=True, hedging=False)
+                            group_by_domain=True)
         cats = [name for name, p in eng.params.items()
                 if isinstance(p, dict) and "w_cat" in p]
         assert cats, "expected pre-concatenated weights on rewritten nodes"
@@ -394,85 +387,12 @@ class TestPrecatWeights:
                 w.shape[0] for w in ws)
 
 
-class TestHedging:
-    def test_runner_duplicates_straggler_first_result_wins(self):
-        calls = []
-        lock = threading.Lock()
-
-        def flaky(x):
-            with lock:
-                calls.append(x)
-                first = len(calls) == 1
-            if first:
-                time.sleep(0.25)           # primary straggles
-            return x * 2
-
-        policy = HedgePolicy(min_hedge_ms=20.0)
-        runner = HedgedRunner(flaky, policy)
-        try:
-            # prime the window so the deadline is the 20ms floor
-            for _ in range(20):
-                policy.observe(1.0)
-            result, outcome = runner.run(21)
-            assert result == 42
-            assert outcome.hedged and outcome.winner == "hedge"
-            assert runner.hedges_launched == 1 and runner.hedge_wins == 1
-            assert len(calls) == 2         # duplicate actually executed
-        finally:
-            runner.close()
-
-    def test_fast_primary_not_hedged(self):
-        runner = HedgedRunner(lambda x: x + 1, HedgePolicy(min_hedge_ms=500.0))
-        try:
-            result, outcome = runner.run(1)
-            assert result == 2 and not outcome.hedged
-            assert outcome.winner == "primary"
-        finally:
-            runner.close()
-
-    def test_engine_hedges_and_scores_stay_exact(self, paper):
-        graph, params, user_in = paper
-        # a primed near-zero deadline plus a forced straggle on the primary
-        # makes the duplicate deterministic — the staged dispatch path is
-        # now fast enough that the primary can beat wait()'s own wake-up,
-        # so a pure timing race would flake. The property under test is
-        # that duplicate execution never changes scores.
-        policy = HedgePolicy(min_hedge_ms=1e-4)
-        eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            hedging=True, hedge_policy=policy)
-        ref = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            hedging=False)
-        req = _request(graph, user_in, 0, 30, seed=1)
-        eng.score(req)                     # compile (never hedged)
-        ref_scores = ref.score(req).scores
-        dispatch = eng._hedged.fn
-
-        def straggling(*args):
-            time.sleep(0.003)              # >> deadline: always straggles
-            return dispatch(*args)
-
-        eng._hedged.fn = straggling
-        hedged = 0
-        for _ in range(5):
-            # re-prime: run() observes its own (slowed) latencies, which
-            # would otherwise lift the deadline past the straggle
-            policy.lat.clear()
-            for _ in range(32):
-                policy.observe(1e-4)
-            r = eng.score(req)
-            hedged += r.hedged
-            np.testing.assert_array_equal(r.scores, ref_scores)
-        assert hedged >= 1
-        eng.close()
-
-
 class TestShardedStage2:
     def test_single_device_bit_identical(self, paper):
         graph, params, user_in = paper
-        ref = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            hedging=False)
+        ref = ServingEngine(graph, params, mode="mari", max_batch=64)
         sh = ServingEngine(graph, params, mode="mari", max_batch=64,
-                           shard_candidates=True, hedging=False)
+                           shard_candidates=True)
         reqs = [_request(graph, user_in, u, n, seed=u + 1)
                 for u, n in ((0, 21), (1, 40))]
         _assert_bit_identical(ref.score_coalesced(reqs),
@@ -502,10 +422,9 @@ def req(uid, n, seed):
     return ServeRequest(uid, {k: v for k, v in feeds.items() if k in user_in},
                         {k: v for k, v in feeds.items() if k not in user_in})
 reqs = [req(0, 21, 1), req(1, 40, 2), req(2, 9, 3)]
-ref = ServingEngine(graph, params, mode="mari", max_batch=64, min_bucket=16,
-                    hedging=False)
+ref = ServingEngine(graph, params, mode="mari", max_batch=64, min_bucket=16)
 sh = ServingEngine(graph, params, mode="mari", max_batch=64, min_bucket=16,
-                   shard_candidates=True, hedging=False)
+                   shard_candidates=True)
 assert sh.mesh.devices.size == 8, sh.mesh
 a = ref.score_coalesced(reqs)
 b = sh.score_coalesced(reqs)
@@ -525,8 +444,7 @@ print("SHARDED-OK")
 class TestBatcherRuntime:
     def test_burst_coalesces_into_few_batches(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=256,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=256)
         reqs = [_request(graph, user_in, u, 20, seed=u) for u in range(6)]
         eng.score(reqs[0])                       # compile before timing paths
         # group closes at max_coalesce, not on linger expiry — deterministic
@@ -539,8 +457,7 @@ class TestBatcherRuntime:
 
     def test_submit_returns_future(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=64)
         with CoalescingBatcher(eng, linger_ms=1.0) as b:
             fut = b.submit(_request(graph, user_in, 0, 10, seed=1))
             res = fut.result(timeout=120)
@@ -548,8 +465,7 @@ class TestBatcherRuntime:
 
     def test_error_propagates_to_waiters(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=64)
         bad = ServeRequest(0, {}, {"item_feats": np.zeros((4, 3))})
         with CoalescingBatcher(eng, linger_ms=1.0) as b:
             fut = b.submit(bad)
@@ -558,8 +474,7 @@ class TestBatcherRuntime:
 
     def test_closed_batcher_rejects(self, paper):
         graph, params, user_in = paper
-        eng = ServingEngine(graph, params, mode="mari", max_batch=64,
-                            hedging=False)
+        eng = ServingEngine(graph, params, mode="mari", max_batch=64)
         b = CoalescingBatcher(eng, auto_start=False)
         with pytest.raises(RuntimeError):
             b.submit(_request(graph, user_in, 0, 10, seed=1))
@@ -723,8 +638,7 @@ class TestDeviceResidentTier:
     PRESETS = {"vani": "vanilla", "uoi": "uoi", "mari": "paper"}
 
     def _plan(self, preset, **evolve):
-        base = dict(batch__max_batch=64, batch__min_bucket=8,
-                    batch__hedging=False)
+        base = dict(batch__max_batch=64, batch__min_bucket=8)
         base.update(evolve)
         return ServePlan.preset(preset).evolve(**base)
 
